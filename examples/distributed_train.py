@@ -50,6 +50,13 @@ from repro.sharding import Partitioner
 from repro.train import TrainConfig, Trainer, TrainerConfig
 
 
+def _worker_env() -> dict:
+    """Environment for a demo worker subprocess: the parent's, held to the
+    CPU.  The multi-rank demos exercise the master tree, not the chip, and
+    a chip belongs to one process — the workers must not contend for it."""
+    return {**os.environ, "JAX_PLATFORMS": "cpu"}
+
+
 def config_100m():
     base = get_config("h2o-danube-1.8b")
     return dataclasses.replace(
@@ -186,7 +193,7 @@ def run_live(args) -> int:
     # driver-side read of the global master (composite + per-rank breakdown)
     gclient = StreamClient(global_m.addr)
 
-    env = dict(os.environ)
+    env = _worker_env()
     procs = []
     for r in range(args.live_ranks):
         out = os.path.join(root, f"r{r}")
@@ -516,7 +523,7 @@ def run_chaos(args) -> int:
         ]
         if args.inject_fault:
             cmd += ["--chaos-fault", args.inject_fault]
-        procs[r] = subprocess.Popen(cmd, env=dict(os.environ))
+        procs[r] = subprocess.Popen(cmd, env=_worker_env())
 
     def _rank_of(source):
         m = re.search(r"rank(\d+)$", source)
@@ -622,7 +629,7 @@ def run_chaos(args) -> int:
             "--chaos-src", rank_source[r],
             "--chaos-ckpt", os.path.join(root, f"r{r}", "ckpt"),
         ]
-        p = subprocess.Popen(cmd, env=dict(os.environ))
+        p = subprocess.Popen(cmd, env=_worker_env())
         procs[r] = p
         out_dirs[r] = out
         return p

@@ -410,11 +410,11 @@ def jaxrt_model() -> APIModel:
                 result=P("ptr", "ptr"),
             ),
             APISpec("free", params=(P("ptr", "ptr"),), result=P("status", "u32")),
-            APISpec(
+            APISpec(  # one blocking wait per fenced dispatch (default mode);
+                # the spinning wait is ust_repro:poll_ready (full mode)
                 "block_until_ready",
                 params=(P("handle", "ptr"),),
                 result=P("status", "u32"),
-                meta=(("Polling", P("handle", "ptr")),),
             ),
         ),
     )

@@ -511,12 +511,16 @@ def fold_trace(trace_dir: str, jobs: int = 1, use_sidecar: bool = True) -> Tally
     if jobs <= 1:
         tally = _fold_groups(trace_dir, groups, use_sidecar, meta=meta)
     else:
+        import multiprocessing
         from concurrent.futures import ProcessPoolExecutor
 
         shards = _partition_groups(groups, jobs)
         tally = Tally()
         try:
-            with ProcessPoolExecutor(max_workers=len(shards)) as ex:
+            # spawn, not fork: the caller may hold an accelerator (and JAX's
+            # threads), which a forked child must not inherit
+            ctx = multiprocessing.get_context("spawn")
+            with ProcessPoolExecutor(max_workers=len(shards), mp_context=ctx) as ex:
                 futures = [
                     ex.submit(_fold_shard, trace_dir, shard, use_sidecar)
                     for shard in shards

@@ -134,9 +134,9 @@ class TracedJit:
       * ``compile`` entry/exit on first trace (cache_hit=False) — ≙ module load
       * ``launch`` span — device begin/end timestamps around execution
         (GPU-profiling meta-parameter, Fig 2 Scenario 2). Device completion is
-        fenced with ``block_until_ready``; in *full* mode the fence is a
-        polling loop emitting ``poll_ready`` events — the spin-lock pattern
-        behind zeEventHostSynchronize's 9.9M calls in the §4.3 tally.
+        fenced inside a ``block_until_ready`` span; in *full* mode the wait
+        is a polling loop emitting ``poll_ready`` events — the spin-lock
+        pattern behind zeEventHostSynchronize's 9.9M calls in the §4.3 tally.
     """
 
     def __init__(
@@ -215,15 +215,19 @@ class TracedJit:
         return out
 
     def _fence(self, out, tr) -> None:
+        """Wait for the device: one ``block_until_ready`` span per call.  In
+        full mode the wait inside it is a polling loop that emits
+        ``poll_ready`` events while the device works."""
+        th = tp()
+        th.block_entry(0)
         if tr.full:
-            # polling fence: emit poll_ready events while the device works.
             # The fused pair recorder + hoisted lookups keep the spin loop
             # itself off the measurement: one recorder call per poll, no
             # attribute chasing per iteration (§4.3's 9.9M-call pattern).
             leaves = [x for x in jax.tree_util.tree_leaves(out) if hasattr(x, "is_ready")]
             if leaves:
                 h = device_ptr(leaves[-1])
-                poll = tp().poll_pair
+                poll = th.poll_pair
                 _now = now
                 spins = 0
                 while not all(x.is_ready() for x in leaves):
@@ -232,11 +236,8 @@ class TracedJit:
                     if spins > 4:
                         time.sleep(0)
                 poll(h, _now(), True)
-            return
-        th = tp()
-        hdl = 0
-        th.block_entry(hdl)
-        jax.block_until_ready(out)
+        else:
+            jax.block_until_ready(out)
         th.block_exit(0)
 
 

@@ -66,7 +66,10 @@ def _run(args) -> int:
     sys.argv = [target] + list(args.args)
     try:
         with Tracer(cfg) as tr:
-            fn()
+            try:
+                rc = fn()
+            except SystemExit as e:
+                rc = e.code
     finally:
         sys.argv = old_argv
     h = tr.handle
@@ -81,7 +84,12 @@ def _run(args) -> int:
     if args.stream_to:
         line += f" streamed={h.streamed} stream_dropped={h.stream_dropped}"
     print(line)
-    return 0
+    # the target's verdict is the run's: a return value or SystemExit code
+    # (None → 0; a message, as sys.exit("...") gives, → 1)
+    if rc is None or isinstance(rc, int):
+        return rc or 0
+    print(rc, file=sys.stderr)
+    return 1
 
 
 def _tally(args) -> int:

@@ -4,12 +4,12 @@ THAPI's sampling framework is a daemon that polls Level-Zero Sysman counters
 (energy, frequency, memory, fabric, utilization) at a user-defined period
 (default 50 ms) and streams them into the LTTng trace.
 
-Our heterogeneous devices are JAX devices.  On TPU, ``device.memory_stats()``
-exposes HBM occupancy; on this CPU container the same call may return None,
-in which case we fall back to host counters only — the daemon architecture
-(thread + period + counter events into the trace) is identical.  Host RSS and
-CPU% stand in for the power/frequency domains that have no CPU analogue
-(DESIGN.md §2, §7).
+Our heterogeneous devices are JAX devices.  ``device.memory_stats()``
+gives HBM occupancy per TPU chip, and the daemon samples every local
+device; the CPU backend keeps no such statistics, so there only the host
+counters move — the daemon architecture (thread + period + counter events
+into the trace) is identical.  Host RSS and CPU% stand in for the
+power/frequency domains that have no CPU analogue (DESIGN.md §2, §7).
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable, List, Optional, Tuple
 
 _PAGE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
@@ -31,22 +31,41 @@ def read_host_rss() -> int:
         return 0
 
 
-def read_device_memory(device=None) -> tuple:
-    """(in_use, peak, limit) bytes for the given (default: first) device."""
-    try:
-        import jax
+def device_info() -> dict:
+    """The devices JAX runs on, as every result names them."""
+    import jax
 
-        dev = device if device is not None else jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats:
-            return (
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind, "count": jax.device_count()}
+
+
+def read_device_memory() -> List[Tuple[int, int, int]]:
+    """(in_use, peak, limit) bytes for every local device, in device order.
+
+    A backend that keeps no memory statistics (the CPU) reports zeros; any
+    failure to read propagates — on a TPU a failed read is a fault, not a
+    zero.
+    """
+    import jax
+
+    out = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        out.append(
+            (
                 int(stats.get("bytes_in_use", 0)),
                 int(stats.get("peak_bytes_in_use", 0)),
                 int(stats.get("bytes_limit", 0)),
             )
-    except Exception:
-        pass
-    return (0, 0, 0)
+        )
+    return out
+
+
+def device_memory_totals(devices: Optional[List[Tuple[int, int, int]]] = None) -> Tuple[int, ...]:
+    """(in_use, peak, limit) summed over the local devices (or over a
+    ``read_device_memory`` result) — the host-level gauge a stream frame
+    carries."""
+    return tuple(sum(col) for col in zip(*(devices or read_device_memory())))
 
 
 class StepRateGauge:
@@ -120,12 +139,12 @@ class TransferGauge:
 
 
 class TelemetryDaemon:
-    """Sampling thread: one ``ust_thapi:sample`` counter event per period."""
+    """Sampling thread: one ``ust_thapi:sample`` counter event per local device
+    per period."""
 
-    def __init__(self, record: Callable, period_s: float = 0.05, device_index: int = 0):
+    def __init__(self, record: Callable, period_s: float = 0.05):
         self._record = record
         self.period_s = period_s
-        self.device_index = device_index
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._last_cpu = (time.process_time(), time.monotonic())
@@ -141,11 +160,14 @@ class TelemetryDaemon:
         return 100.0 * (pt - lpt) / dw if dw > 0 else 0.0
 
     def sample_once(self) -> None:
-        in_use, peak, limit = read_device_memory()
+        devices = read_device_memory()
         host_rss = read_host_rss()
         cpu_pct = self._cpu_pct()
         step_rate = StepRateGauge.read_and_reset()
         memcpy_bw, alloc_bw = TransferGauge.read_and_reset()
+        for index, (in_use, peak, limit) in enumerate(devices):
+            self._record(index, in_use, peak, limit, host_rss, cpu_pct, step_rate)
+        in_use, peak, limit = device_memory_totals(devices)
         self.last = {
             "mem_in_use": in_use,
             "mem_peak": peak,
@@ -156,15 +178,6 @@ class TelemetryDaemon:
             "memcpy_bw": memcpy_bw,
             "alloc_bw": alloc_bw,
         }
-        self._record(
-            self.device_index,
-            in_use,
-            peak,
-            limit,
-            host_rss,
-            cpu_pct,
-            step_rate,
-        )
         self.samples += 1
 
     def _loop(self) -> None:

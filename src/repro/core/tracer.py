@@ -541,6 +541,7 @@ class Tracer:
                         "interval": self.cfg.sampling_interval,
                         "modes_used": list(self._modes_used),
                     },
+                    **_device_env(),
                 },
                 mode=self.cfg.mode,
             )
@@ -741,7 +742,7 @@ class Tracer:
         if self._sampler is not None:
             last = self._sampler.last
             return dict(last) if last else None
-        in_use, peak, limit = _telemetry.read_device_memory()
+        in_use, peak, limit = _telemetry.device_memory_totals()
         memcpy_bw, alloc_bw = _telemetry.TransferGauge.read_and_reset()
         return {
             "mem_in_use": in_use,
@@ -766,6 +767,18 @@ class Tracer:
             if name.endswith((".ctf", ".ctfcol")):
                 os.unlink(os.path.join(self.cfg.out_dir, name))
         return path
+
+
+def _device_env() -> dict:
+    """The traced process's devices and kernel path, for the trace metadata
+    (nothing when it never imported JAX).  ``kernels`` is what
+    ``repro.kernels.ops`` runs by default, so a run that forced the jnp
+    references onto a chip (``REPRO_KERNELS=ref``) says so."""
+    if "jax" not in sys.modules:
+        return {}
+    from repro.kernels.ops import default_impl
+
+    return {"device": _telemetry.device_info(), "kernels": default_impl()}
 
 
 def trace_session(out_dir: str, mode: str = "default", **kw) -> Tracer:

@@ -1,64 +1,53 @@
-"""Compatibility layer for the jax API surface this repo uses.
+"""The JAX set-up this repo shares: mesh and shard_map helpers, and the
+persistent compilation cache.
 
-The codebase targets the modern mesh/sharding API (``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=)``, ``jax.shard_map(..., check_vma=)``,
-``AbstractMesh(sizes, names)``); older jaxlib builds (< 0.5) predate all four
-spellings.  Every mesh/shard_map construction in the repo goes through these
-helpers so the rest of the code can write the modern form once.
+The mesh helpers spell Auto axis types once, so every mesh in the repo is
+built the same way; the repo targets the JAX pinned in requirements.txt.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Sequence
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # noqa: F401
-
-    _HAS_AXIS_TYPE = True
-except ImportError:
-    AxisType = None
-    _HAS_AXIS_TYPE = False
-
-try:  # jax >= 0.6 exposes shard_map at top level (check_vma spelling)
-    from jax import shard_map as _new_shard_map
-except ImportError:
-    _new_shard_map = None
-    from jax.experimental.shard_map import shard_map as _old_shard_map
+#: the checkout root (src/repro/jaxcompat.py → three levels up)
+CHECKOUT = Path(__file__).resolve().parents[2]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names), axis_types=(AxisType.Auto,) * len(axis_names)
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), axis_types=(AxisType.Auto,) * len(axis_names)
+    )
 
 
 def device_mesh(devices, axis_names: Sequence[str]) -> Mesh:
-    """``Mesh`` over an explicit device array, Auto axis types where supported."""
-    if _HAS_AXIS_TYPE:
-        return Mesh(devices, tuple(axis_names), axis_types=(AxisType.Auto,) * len(axis_names))
-    return Mesh(devices, tuple(axis_names))
+    """``Mesh`` over an explicit device array, Auto axis types."""
+    return Mesh(devices, tuple(axis_names), axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> AbstractMesh:
-    """``AbstractMesh(sizes, names)``; old jax spells it ``((name, size), ...)``."""
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map``; ``check_vma`` maps to legacy ``check_rep``."""
-    if _new_shard_map is not None:
-        return _new_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    return _old_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX, which reads it
+    itself.  Otherwise the cache lives at ``<checkout>/.jax_cache``: a fixed
+    path, because the path is part of what a later process must find again.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

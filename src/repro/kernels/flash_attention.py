@@ -3,9 +3,11 @@
 TPU-native adaptation (not a CUDA port): the grid's innermost dimension
 iterates KV blocks sequentially while q/m/l/acc live in VMEM scratch — the
 online-softmax accumulator pattern that keeps the working set in VMEM and
-feeds the MXU [blk_q × d] · [d × blk_k] tiles (d = head_dim = 128 on every
-assigned arch ⇒ lane-aligned).  GQA is handled in the index maps: the KV
-block index is ``h // (H // Kv)``, so no KV replication in memory.
+feeds the MXU [blk_q × d] · [d × blk_k] tiles.  The call works heads-major
+([B,H,S,d]; the wrapper transposes), so each block's last two dims are
+(blk, d) with d the whole head dim — legal TPU tiling for any head_dim.
+GQA is handled in the index maps: the KV block index is ``h // (H // Kv)``,
+so no KV replication in memory.
 
 Block sizes default to 128×128 (MXU-native); the wrapper shrinks them to the
 largest divisor for small test shapes.
@@ -49,10 +51,12 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # [blk_q, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [blk_k, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    q = q_ref[...].astype(jnp.float32)  # [blk_q, d]
+    k = k_ref[...].astype(jnp.float32)  # [blk_k, d]
+    v = v_ref[...].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
 
     qi = pl.program_id(2)
     qpos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0) + q_offset
@@ -64,14 +68,12 @@ def _kernel(
         mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]  # [blk_q, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
@@ -79,7 +81,7 @@ def _kernel(
         lsum = l_scr[...]
         # fully-masked rows (can't happen for causal q_offset>=0, but keep safe)
         denom = jnp.where(lsum == 0.0, 1.0, lsum)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -120,20 +122,25 @@ def flash_attention_pallas(
         q_offset=T - S,
         scale=1.0 / (hd**0.5),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((None, None, blk_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, blk_k, hd), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((None, None, blk_k, hd), lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, blk_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention",
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

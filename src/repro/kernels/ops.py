@@ -1,40 +1,88 @@
 """jit-ready dispatch wrappers for the Pallas kernels.
 
-Selection policy: the Pallas kernels target TPU; on this CPU container they
-run under ``interpret=True`` (validated in tests), while the default runtime
-path uses the jnp references — numerically identical, fast on CPU, and the
-dry-run lowers the same einsum structure XLA:TPU fuses well.
+Selection policy (:func:`default_impl`): on a TPU backend the wrappers run
+the Pallas kernels; on any other backend they run the jnp references in
+``ref.py``, and a caller that forces ``impl="pallas"`` there gets the
+kernels under ``interpret=True`` (how the tests validate them on CPU).
+``REPRO_KERNELS=ref|pallas`` overrides the backend's choice; the tracer
+stamps the effective choice into the trace metadata so a run that swapped
+the references in on a chip says so.
 
-Set ``impl="pallas"`` (or REPRO_KERNELS=pallas) to force the kernels; every
-wrapper also emits a THAPI ``ust_kernel:launch`` span with analytic FLOPs and
-bytes so traced runs attribute device time to the hot spots.
+``ssd`` and ``rglru`` are differentiable on the kernel path: a
+``jax.custom_vjp`` runs the Pallas kernel forward and takes the backward as
+the VJP of the jnp reference (traced as its own ``*[ref_vjp]`` kernel span).
+
+Every wrapper emits a THAPI ``ust_kernel:launch`` span with analytic FLOPs
+and bytes.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.interception import kernel_span
+from repro.jaxcompat import shard_map
 
 from . import ref as _ref
 
 
+def default_impl() -> str:
+    """The path a wrapper takes when the caller names none: ``REPRO_KERNELS``
+    when set, else ``pallas`` on TPU and ``ref`` elsewhere."""
+    return os.environ.get("REPRO_KERNELS") or (
+        "pallas" if jax.default_backend() == "tpu" else "ref"
+    )
+
+
 def _impl(impl: Optional[str]) -> str:
-    if impl is not None:
-        return impl
-    env = os.environ.get("REPRO_KERNELS", "")
-    if env:
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return impl if impl is not None else default_impl()
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _per_shard(kernel, mesh, batched_in, batched_out):
+    """Run ``kernel`` once per data-parallel shard of ``mesh``.
+
+    XLA cannot partition a Mosaic kernel, so on a mesh of several devices
+    the call goes through shard_map: arguments flagged in ``batched_in`` /
+    results in ``batched_out`` split their leading (batch) dim over the
+    mesh's data axes, everything else is replicated.
+    """
+    if mesh is None or mesh.size == 1:
+        return kernel
+    dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
+
+    def specs(flags):
+        return tuple(P(dp) if f else P() for f in flags)
+
+    return shard_map(kernel, mesh, specs(batched_in), specs(batched_out))
+
+
+def _with_ref_vjp(kernel, reference, name: str):
+    """``kernel`` forward, the VJP of the jnp ``reference`` backward."""
+
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        with kernel_span(f"{name}[ref_vjp]"):
+            return jax.vjp(reference, *args)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +118,14 @@ def rglru(x, r, i, lam, h0=None, *, impl=None):
         if _impl(impl) == "pallas":
             from .rglru_scan import rglru_pallas
 
-            return rglru_pallas(x, r, i, lam, h0=h0, interpret=_interpret())
+            if h0 is None:
+                h0 = jnp.zeros((B, C), jnp.float32)
+            f = _with_ref_vjp(
+                functools.partial(rglru_pallas, interpret=_interpret()),
+                _ref.rglru_ref,
+                "rglru_scan",
+            )
+            return f(x, r, i, lam, h0)
         return _ref.rglru_ref(x, r, i, lam, h0=h0)
 
 
@@ -83,19 +138,27 @@ def rglru_step(h, x_t, r_t, i_t, lam):
 # ---------------------------------------------------------------------------
 
 
-def ssd(x, dt, A_log, Bm, Cm, D, *, chunk: int = 64, state0=None, impl=None):
+def ssd(x, dt, A_log, Bm, Cm, D, *, chunk: int = 64, mesh=None, impl=None):
     B, S, H, P = x.shape
     N = Bm.shape[-1]
-    flops = B * S * H * (2 * P * N * 3 + 2 * 64 * P)  # states + intra approx
+    flops = B * S * H * (2 * P * N * 3 + 2 * chunk * P)  # states + intra approx
     nbytes = (x.size + Bm.size * 2) * x.dtype.itemsize * 2
     with kernel_span("ssd_scan", (B, H, S // chunk), flops, nbytes):
         if _impl(impl) == "pallas":
             from .ssd_scan import ssd_pallas
 
-            return ssd_pallas(
-                x, dt, A_log, Bm, Cm, D, chunk=chunk, state0=state0, interpret=_interpret()
+            f = _with_ref_vjp(
+                _per_shard(
+                    functools.partial(ssd_pallas, chunk=chunk, interpret=_interpret()),
+                    mesh,
+                    (True, True, False, True, True, False),
+                    (True, True),
+                ),
+                functools.partial(_ref.ssd_ref, chunk=chunk),
+                "ssd_scan",
             )
-        return _ref.ssd_ref(x, dt, A_log, Bm, Cm, D, chunk=chunk, state0=state0)
+            return f(x, dt, A_log, Bm, Cm, D)
+        return _ref.ssd_ref(x, dt, A_log, Bm, Cm, D, chunk=chunk)
 
 
 def ssd_step(state, x_t, dt_t, A_log, B_t, C_t, D):

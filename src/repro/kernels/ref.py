@@ -121,7 +121,9 @@ def ssd_ref(x, dt, A_log, Bm, Cm, D, chunk: int = 64, state0=None):
     cum = jnp.cumsum(af, axis=2)  # [B,nc,L,H]
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,i,j,H]
     ii, jj = jnp.meshgrid(jnp.arange(chunk), jnp.arange(chunk), indexing="ij")
-    LT = jnp.where((jj <= ii)[None, None, :, :, None], jnp.exp(seg), 0.0)
+    # mask before exp: above the diagonal seg > 0 can overflow, and the
+    # gradient of where(mask, exp(seg), 0) there is 0 · inf = NaN
+    LT = jnp.exp(jnp.where((jj <= ii)[None, None, :, :, None], seg, -jnp.inf))
     # intra-chunk: y[i] = Σ_j C_i·B_j · L[i,j] · dt_j · x_j
     CB = jnp.einsum("bcihn,bcjhn->bcijh", Ch, Bh)  # [B,nc,i,j,H]
     W = CB * LT * dtf[:, :, None, :, :]

@@ -1,11 +1,14 @@
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# ^ MUST precede every other import (jax locks the device count on first
-# init).  512 placeholder host devices back the 2×16×16 production mesh; the
-# dry-run lowers + compiles every (arch × shape × mesh) cell with
-# ShapeDtypeStructs — no arrays are ever allocated.
+# ^ MUST precede every other import (jax locks the platform and device count
+# on first init).  The dry-run is a CPU-lowering tool: it never touches an
+# accelerator, so it can run beside a process that holds the chip.  512
+# placeholder host devices back the 2×16×16 production mesh; the dry-run
+# lowers + compiles every (arch × shape × mesh) cell with ShapeDtypeStructs —
+# no arrays are ever allocated.
 
 """Multi-pod dry-run (assignment deliverable e).
 

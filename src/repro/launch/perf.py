@@ -1,5 +1,6 @@
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """§Perf hillclimb driver — the three chosen cells, hypothesis → change →

@@ -1,10 +1,12 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch × shape × mesh), in seconds (§Roofline):
+Three terms per (arch × shape × mesh), in seconds (§Roofline), against the
+peaks of the chip the program targets (:data:`PEAKS`, keyed by JAX's
+``device_kind``; the dry-run targets TPU v5e):
 
-    compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF/s bf16)
-    memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-    collective = collective_bytes_per_device / link_bw      (~50 GB/s ICI)
+    compute    = HLO_FLOPs_per_device / peak_FLOPs
+    memory     = HLO_bytes_per_device / HBM_bw
+    collective = collective_bytes_per_device / link_bw
 
 ``compiled.cost_analysis()`` supplies per-device FLOPs/bytes (the compiled
 module IS the per-device program after SPMD partitioning).  Collective bytes
@@ -28,10 +30,40 @@ import dataclasses
 import re
 from typing import Dict
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per link
+    source: str
+
+
+#: published per-chip peaks by ``jax.devices()[i].device_kind``
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12,
+        hbm_bw=819e9,
+        ici_bw=50e9,  # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+#: the chip the dry-run and perf drivers compile for
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Peaks of ``device_kind``; a kind without published peaks here is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them to "
+            f"repro.launch.roofline.PEAKS with their source (known: {sorted(PEAKS)})"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -114,18 +146,23 @@ class Roofline:
     out_bytes: int = 0
     temp_bytes: int = 0
     peak_bytes: int = 0
+    device_kind: str = TARGET_KIND
+
+    @property
+    def peaks(self) -> Peaks:
+        return peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -144,7 +181,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """useful compute time / bound time — the score we hillclimb."""
         bound = max(self.t_compute, self.t_memory, self.t_collective)
-        return (self.model_flops / PEAK_FLOPS) / bound if bound else 0.0
+        return (self.model_flops / self.peaks.flops) / bound if bound else 0.0
 
     def to_json(self) -> dict:
         return {
@@ -164,6 +201,7 @@ class Roofline:
             "out_bytes": self.out_bytes,
             "temp_bytes": self.temp_bytes,
             "peak_bytes": self.peak_bytes,
+            "device_kind": self.device_kind,
         }
 
 
@@ -206,21 +244,18 @@ def extrapolate(m1: dict, m2: dict, units: float) -> dict:
 
 
 def memory_stats(compiled) -> dict:
-    try:
-        ma = compiled.memory_analysis()
-        return {
-            "arg_bytes": int(getattr(ma, "argument_size_in_bytes", 0)),
-            "out_bytes": int(getattr(ma, "output_size_in_bytes", 0)),
-            "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
-            "peak_bytes": int(
-                getattr(ma, "argument_size_in_bytes", 0)
-                + getattr(ma, "output_size_in_bytes", 0)
-                + getattr(ma, "temp_size_in_bytes", 0)
-                - getattr(ma, "alias_size_in_bytes", 0)
-            ),
-        }
-    except Exception:
-        return {}
+    ma = compiled.memory_analysis()
+    return {
+        "arg_bytes": int(ma.argument_size_in_bytes),
+        "out_bytes": int(ma.output_size_in_bytes),
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "peak_bytes": int(
+            ma.argument_size_in_bytes
+            + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes
+            - ma.alias_size_in_bytes
+        ),
+    }
 
 
 def roofline_from(meas: dict, model_flops: float, mem: dict) -> "Roofline":
@@ -246,35 +281,3 @@ def model_flops_for(cfg, shape, n_devices: int) -> float:
         total = 2 * n_active * shape.global_batch
     return total / n_devices
 
-
-def analyze(compiled, cfg, shape, n_devices: int) -> Roofline:
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
-    flops = float(cost.get("flops", 0.0))
-    nbytes = float(cost.get("bytes accessed", 0.0))
-    coll = collective_bytes(compiled.as_text())
-    mem = {}
-    try:
-        ma = compiled.memory_analysis()
-        mem = {
-            "arg_bytes": int(getattr(ma, "argument_size_in_bytes", 0)),
-            "out_bytes": int(getattr(ma, "output_size_in_bytes", 0)),
-            "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
-            "peak_bytes": int(
-                getattr(ma, "argument_size_in_bytes", 0)
-                + getattr(ma, "output_size_in_bytes", 0)
-                + getattr(ma, "temp_size_in_bytes", 0)
-            ),
-        }
-    except Exception:
-        pass
-    return Roofline(
-        flops=flops,
-        bytes_accessed=nbytes,
-        coll_bytes=float(coll.total_bytes),
-        coll_counts=coll.counts,
-        coll_by_kind=coll.bytes_by_kind,
-        model_flops=model_flops_for(cfg, shape, n_devices),
-        **mem,
-    )

@@ -57,7 +57,11 @@ class Model:
         if self.cfg.family == "moe":
             out, aux = self.mod.forward_train(self.cfg, params, batch, mesh=self.mesh)
             return out, aux
-        return self.mod.forward_train(self.cfg, params, batch), jnp.zeros((), jnp.float32)
+        if self.cfg.family == "ssm":  # the SSD kernel runs per data shard
+            out = self.mod.forward_train(self.cfg, params, batch, mesh=self.mesh)
+        else:
+            out = self.mod.forward_train(self.cfg, params, batch)
+        return out, jnp.zeros((), jnp.float32)
 
     def loss(self, params, batch):
         logits, aux = self.logits(params, batch)
@@ -72,7 +76,7 @@ class Model:
 
     # -- serving -----------------------------------------------------------------
     def prefill(self, params, batch, cache_len: int):
-        if self.cfg.family == "moe":
+        if self.cfg.family in ("moe", "ssm"):
             return self.mod.prefill(self.cfg, params, batch, cache_len, mesh=self.mesh)
         return self.mod.prefill(self.cfg, params, batch, cache_len)
 

@@ -73,7 +73,7 @@ def _mix(cfg: ModelConfig, p: dict, h, conv_state=None):
     return z, xs, Bm, Cm, dt, conv_tail
 
 
-def block(cfg: ModelConfig, p: dict, x):
+def block(cfg: ModelConfig, p: dict, x, mesh=None):
     di, nh, G, N, _ = _dims(cfg)
     B, S, _ = x.shape
     h = rmsnorm(x, p["ln"]["w"])
@@ -86,15 +86,16 @@ def block(cfg: ModelConfig, p: dict, x):
         Cm.reshape(B, S, G, N),
         p["D"],
         chunk=min(cfg.ssm.chunk, S),
+        mesh=mesh,
     )
     y = y.reshape(B, S, di)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"])
     return x + jnp.einsum("bse,ed->bsd", y, p["wo"])
 
 
-def forward_train(cfg: ModelConfig, params: dict, batch: dict):
+def forward_train(cfg: ModelConfig, params: dict, batch: dict, mesh=None):
     x = embed(params["embed"], batch["tokens"])
-    body = _remat(cfg, lambda h, pl: (block(cfg, pl, h), None))
+    body = _remat(cfg, lambda h, pl: (block(cfg, pl, h, mesh), None))
     x, _ = model_scan(cfg, body, x, params["blocks"])
     x = rmsnorm(x, params["ln_f"]["w"])
     return unembed(cfg, params["embed"], x)
@@ -121,7 +122,7 @@ def cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
     }
 
 
-def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int):
+def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=None):
     tokens = batch["tokens"]
     di, nh, G, N, _ = _dims(cfg)
     B, S = tokens.shape
@@ -138,6 +139,7 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int):
             Cm.reshape(B, S, G, N),
             pl["D"],
             chunk=min(cfg.ssm.chunk, S),
+            mesh=mesh,
         )
         y = y.reshape(B, S, di)
         y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"])

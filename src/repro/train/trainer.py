@@ -147,8 +147,9 @@ class Trainer:
             self.batch_shardings,
         ) = build_train_artifacts(model, partitioner, shape, tcfg)
         self.state = init_state(model, tcfg, jax.random.PRNGKey(rng_seed), self.state_shardings)
-        dp = partitioner.dp_size()
-        self.pipe = SyntheticPipeline(model, shape, cfg.data, dp_rank=0, dp_size=dp)
+        # one process feeds every local device, so it generates the whole
+        # global batch (a per-rank slice would not fill the batch sharding)
+        self.pipe = SyntheticPipeline(model, shape, cfg.data)
         self.ckpt = Checkpointer(cfg.ckpt_dir) if cfg.ckpt_dir else None
         self.step = 0
         self.history: List[Dict[str, float]] = []
@@ -266,7 +267,6 @@ class Trainer:
 
     # -- batching -----------------------------------------------------------------
     def _device_batch(self, host_batch: Dict[str, np.ndarray]):
-        # dp_size=world here (single-process container): host batch is global
         return {
             k: jax.device_put(v, self.batch_shardings[k]) for k, v in host_batch.items()
         }
@@ -327,9 +327,10 @@ class Trainer:
             osp.outs["lr"] = float(metrics["lr"])
         StepRateGauge.bump()
         self.step += 1
-        self.history.append({"step": self.step, "loss": loss, "grad_norm": gnorm})
+        dt = time.monotonic() - t0
+        self.history.append({"step": self.step, "loss": loss, "grad_norm": gnorm, "time_s": dt})
         if self.ckpt is not None and self.step % self.cfg.ckpt_every == 0:
             self._save()
         # straggler watchdog (EWMA of step wall time; API-level evidence
         # arrives asynchronously via straggler_callback)
-        self.watchdog.observe_step(time.monotonic() - t0)
+        self.watchdog.observe_step(dt)

@@ -1,5 +1,7 @@
 """Tiny traced workload used by the iprof CLI tests."""
 
+import sys
+
 import jax.numpy as jnp
 
 from repro.core import collective_span, traced_jit, train_step_span
@@ -15,3 +17,13 @@ def main():
             sp.outs["grad_norm"] = 1.0
         with collective_span("all_reduce", 256, "data", 4):
             pass
+
+
+def returns_2():
+    main()
+    return 2
+
+
+def exits_2():
+    main()
+    sys.exit(2)

@@ -122,3 +122,45 @@ def test_combine_ranks(tmp_path, capsys):
 
     m = re.search(r"train_step.*?\|\s+(\d+)\s+\|", text)
     assert m and int(m.group(1)) == 12
+
+
+@pytest.mark.parametrize("entry", ["returns_2", "exits_2"])
+def test_run_propagates_target_exit_code(tmp_path, entry):
+    """A target that returns 2, or raises SystemExit(2), makes the run exit 2
+    — with its trace still written."""
+    out = str(tmp_path / "t")
+    assert iprof(["run", "-o", out, f"tests.iprof_target:{entry}"]) == 2
+    assert ("ust_repro", "train_step") in tally_trace(out).apis
+
+
+@pytest.mark.parametrize(
+    "entry,argv,rows",
+    [
+        (
+            "repro.launch.serve:main",
+            ["--requests", "3", "--prompt-len", "8", "16", "--new-tokens", "3"],
+            ("prefill", "decode_step", "dispatch", "block_until_ready"),
+        ),
+        (
+            "repro.launch.train:main",
+            ["--steps", "2", "--seq", "16", "--batch", "2"],
+            ("train_step", "data_next", "dispatch", "block_until_ready"),
+        ),
+    ],
+)
+def test_launchers_under_iprof_run(tmp_path, entry, argv, rows):
+    """The entry points a user traces run under iprof and tally their spans;
+    the report names the device and the kernel path."""
+    out, report = tmp_path / "t", tmp_path / "report.json"
+    rc = iprof(
+        ["run", "-o", str(out), entry, "--", "--arch", "mamba2-1.3b", "--smoke", *argv,
+         "--report", str(report)]
+    )
+    assert rc == 0
+    t = tally_trace(str(out))
+    names = {api for _, api in t.apis}
+    assert set(rows) <= names and t.discarded == 0
+    rep = json.loads(report.read_text())
+    assert rep["device"]["platform"] == "cpu" and rep["kernels"] == "ref"
+    meta = json.loads((out / "metadata.json").read_text())
+    assert meta["env"]["device"] == rep["device"] and meta["env"]["kernels"] == "ref"

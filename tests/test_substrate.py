@@ -204,3 +204,34 @@ def test_fsdp_rules_shard_embed_over_data():
     part = Partitioner(mesh, fsdp=True)
     spec = part.pspec(("embed", "mlp"), (4096, 1600))
     assert spec == P("data", "model")
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_honours_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself; the helper sets
+    nothing."""
+    from repro.jaxcompat import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_fixed_checkout_path(monkeypatch):
+    """Unset: a fixed path in the checkout, the same on every call."""
+    from repro.jaxcompat import CHECKOUT, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(CHECKOUT / ".jax_cache") == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert (CHECKOUT / "src" / "repro" / "jaxcompat.py").is_file()

@@ -567,6 +567,44 @@ def remediation_model() -> APIModel:
     )
 
 
+def serving_model() -> APIModel:
+    """ust_repro spans around the whole serve step, the request queue and
+    the tracer's own consumer thread.
+
+    A trailing :class:`APIModel` like :func:`remediation_model`, so every
+    earlier event id stays the same.  ``queue_wait`` is one fused pair
+    recorded at admission whose entry carries the stamp ``submit()`` took.
+    ``consumer_drain`` is one fused pair per consumer tick, recorded on the
+    consumer thread when the tick is done: its entry is stamped just after
+    the tick's ``thapi.clock`` profiler annotation and carries the stamp
+    taken just before it (the annotation's ``ts`` argument), the two ends of
+    the anchor that places trace time on a JAX profile
+    (:func:`repro.core.clock.profile_clock`).
+    """
+    return APIModel(
+        provider="ust_repro",
+        apis=(
+            APISpec(
+                "engine_step",
+                params=(P("step", "u64"), P("active", "u32")),
+                result=P("status", "u32"),
+                meta=(("OutScalar", P("admitted", "u32")), ("OutScalar", P("tokens_out", "u32"))),
+            ),
+            APISpec(
+                "queue_wait",
+                params=(P("request_id", "u64"),),
+                result=P("status", "u32"),
+            ),
+            APISpec(
+                "consumer_drain",
+                params=(P("clock_anchor", "u64"),),
+                result=P("status", "u32"),
+                meta=(("OutScalar", P("records", "u64")), ("OutScalar", P("bytes", "u64"))),
+            ),
+        ),
+    )
+
+
 def builtin_models() -> Tuple[APIModel, ...]:
     return (
         framework_model(),
@@ -576,6 +614,7 @@ def builtin_models() -> Tuple[APIModel, ...]:
         telemetry_model(),
         user_model(),
         remediation_model(),  # appended models keep earlier eids stable
+        serving_model(),
     )
 
 

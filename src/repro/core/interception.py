@@ -22,6 +22,8 @@ fast paths check ``active_tracer()`` once.
 
 from __future__ import annotations
 
+import functools
+import re
 import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Optional, Sequence
@@ -90,6 +92,7 @@ class _TP:
         self.memcpy_pair = p["ust_jaxrt:memcpy"]
         self.alloc_pair = p["ust_jaxrt:alloc"]
         self.poll_pair = p["ust_repro:poll_ready"]
+        self.queue_wait_pair = p["ust_repro:queue_wait"]
         self.kernel_span = r["ust_kernel:launch_span"]
         self.transfer_span = r["ust_kernel:transfer_span"]
         self.coll = {
@@ -107,6 +110,7 @@ class _TP:
                 "optimizer_update",
                 "prefill",
                 "decode_step",
+                "engine_step",
             )
         }
 
@@ -126,11 +130,26 @@ def tp() -> _TP:
 # ---------------------------------------------------------------------------
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name`` made an identifier, so that jit names its
+    program ``jit_<name>`` (the profiler's module and ``PjitFunction``
+    names) whatever ``fn`` is called, a lambda included."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = re.sub(r"\W+", "_", name).strip("_") or "fn"
+    return program
+
+
 class TracedJit:
     """jax.jit wrapper with THAPI interception.
 
     Records per call:
       * ``dispatch`` entry/exit — fn name, #args, argument bytes, donated bytes
+        (the jitted program takes the name too: ``jit_<name>``, made an
+        identifier, in the compiler's and the profiler's records)
       * ``compile`` entry/exit on first trace (cache_hit=False) — ≙ module load
       * ``launch`` span — device begin/end timestamps around execution
         (GPU-profiling meta-parameter, Fig 2 Scenario 2). Device completion is
@@ -159,8 +178,8 @@ class TracedJit:
             kw["in_shardings"] = in_shardings
         if out_shardings is not None:
             kw["out_shardings"] = out_shardings
-        self._jit = jax.jit(fn, **kw)
         self.name = name or getattr(fn, "__name__", "jit_fn")
+        self._jit = jax.jit(_named(fn, self.name), **kw)
         self.donate_argnums = frozenset(donate_argnums)
         self.flops = flops
         self.bytes_accessed = bytes_accessed
@@ -412,3 +431,17 @@ def prefill_span(request_id: int, batch: int, seq_len: int) -> _FrameworkSpan:
 
 def decode_step_span(request_id: int, batch: int, cache_len: int) -> _FrameworkSpan:
     return _FrameworkSpan("decode_step", (request_id, batch, cache_len), ("tokens_out",))
+
+
+def engine_step_span(step: int, active: int) -> _FrameworkSpan:
+    """The whole of one serve step: admissions, decode, readback and
+    bookkeeping; outs ``admitted`` and ``tokens_out``."""
+    return _FrameworkSpan("engine_step", (step, active), ("admitted", "tokens_out"))
+
+
+def record_queue_wait(request_id: int, since_ns: int) -> None:
+    """One ``queue_wait`` pair for a request admitted now: its entry at
+    ``since_ns``, the trace-clock stamp taken when it was submitted."""
+    tr = active_tracer()
+    if tr is not None and tr.selected and tr.fidelity != "off":
+        tp().queue_wait_pair(request_id, since_ns, 0)

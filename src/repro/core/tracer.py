@@ -34,7 +34,7 @@ from typing import Dict, Optional, Sequence, Set, Tuple
 
 from . import telemetry as _telemetry
 from .api_model import TraceModel, builtin_trace_model
-from .clock import ClockInfo, now
+from .clock import PROFILE_MARK, ClockInfo, now
 from .ctf import StreamWriter, trace_size_bytes, write_metadata
 from .ringbuffer import RingRegistry
 from .tracepoints import FIDELITY_MODES, Tracepoints
@@ -296,6 +296,11 @@ class Tracer:
         self._modes_used = [cfg.fidelity]
         self._drain_lock = threading.Lock()
         self._seen_drops: Dict[Tuple[int, int], int] = {}
+        #: records the rings had published at the last drain
+        self._drained = 0
+        #: jax.profiler.TraceAnnotation when the process has loaded JAX: the
+        #: consumer's clock anchor on a running profile (else None)
+        self._profile_mark = None
         #: final in-process folded tally (set at stop() when an online
         #: analyzer ran — always the case for tally-only sessions)
         self.final_tally = None
@@ -477,6 +482,10 @@ class Tracer:
                     self.cluster.on_flag = self.remediation.ingest_flag
                 if getattr(self.cluster, "on_healthy", None) is None:
                     self.cluster.on_healthy = self.remediation.observe_healthy
+        if "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+
+            self._profile_mark = TraceAnnotation
         self._stop_evt.clear()
         self._consumer = threading.Thread(
             target=self._consumer_loop, name="thapi-consumer", daemon=True
@@ -601,11 +610,11 @@ class Tracer:
         self.stop()
 
     # -- consumer daemon -------------------------------------------------------
-    def _drain_once(self) -> None:
+    def _drain_once(self) -> Tuple[int, int]:
         with self._drain_lock:
-            self._drain_unlocked()
+            return self._drain_unlocked()
 
-    def _drain_unlocked(self) -> None:
+    def _drain_unlocked(self) -> Tuple[int, int]:
         """Drain every ring zero-copy: stream + online analysis read the ring
         storage through ``drain_view`` memoryviews and the region is released
         only after both consumed it — no intermediate ``bytes`` on the common
@@ -619,13 +628,21 @@ class Tracer:
         side FoldEngine) and no ``.ctf`` file is created or appended; ring
         drops are accounted into the online tally instead of a stream
         discard record.  Caller holds ``_drain_lock`` (drains serialize
-        against mid-run rung flips)."""
+        against mid-run rung flips).
+
+        Returns (records, bytes) drained: records as the rings' producer
+        counts, read before each view, so a record counts at the drain
+        that takes it or, published mid-drain, at the next."""
         assert self.registry is not None
         writers = self._writers
         online = self.online
         tally_only = self._fidelity == "tally-only"
+        published = nbytes = 0
         for ring in self.registry.rings():
+            published += ring.events
             regions = ring.drain_view()
+            for r in regions:
+                nbytes += len(r)
             dropped = ring.dropped
             key = (ring.pid, ring.tid)
             if tally_only:
@@ -677,6 +694,8 @@ class Tracer:
                     # discard records go straight to the stream file; the
                     # sidecar's footer tally must account them too
                     cw.note_discard(delta)
+        records, self._drained = published - self._drained, published
+        return records, nbytes
 
     def _new_colwriter(self, w: StreamWriter):
         from .ctf import ColumnarWriter, sidecar_path
@@ -688,8 +707,21 @@ class Tracer:
         return ColumnarWriter(self._fold_engine, w.pid, w.tid, sidecar_path(w.path))
 
     def _consumer_loop(self) -> None:
+        """One tick per ``flush_period_s``: a clock anchor, then the drain,
+        the stream tick and the controllers' ticks, recorded as one fused
+        ``consumer_drain`` pair on this thread's own ring once the tick is
+        done.  A tick that drained nothing but the previous tick's pair
+        records nothing, so an idle session stays empty."""
+        mark = self._profile_mark
+        pair = self.tp.record_pair.get("ust_repro:consumer_drain")
+        own = 0  # records this loop wrote at its last tick
         while not self._stop_evt.wait(self.cfg.flush_period_s):
-            self._drain_once()
+            anchor = now()
+            if mark is not None:
+                with mark(PROFILE_MARK, ts=anchor):
+                    pass
+            start = now()
+            records, nbytes = self._drain_once()
             self._stream_tick()
             if self.adaptive is not None:
                 self.adaptive.tick()
@@ -700,6 +732,11 @@ class Tracer:
                     self.remediation.tick()
                 except Exception:
                     pass  # remediation must never kill the consumer thread
+            if pair is not None and records > own:
+                pair(anchor, start, 0, records, nbytes)
+                own = 2
+            else:
+                own = 0
 
     def _stream_tick(self, final: bool = False) -> None:
         """Push the live tally to the streaming service (§3.7+§6).

@@ -10,10 +10,10 @@ the references in on a chip says so.
 
 ``ssd`` and ``rglru`` are differentiable on the kernel path: a
 ``jax.custom_vjp`` runs the Pallas kernel forward and takes the backward as
-the VJP of the jnp reference (traced as its own ``*[ref_vjp]`` kernel span).
+the VJP of the jnp reference.
 
-Every wrapper emits a THAPI ``ust_kernel:launch`` span with analytic FLOPs
-and bytes.
+The wrappers run while ``jit`` traces the model, once per compile, so they
+record no THAPI span: a kernel's device time is in the device's own trace.
 """
 
 from __future__ import annotations
@@ -24,10 +24,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.interception import kernel_span
 from repro.jaxcompat import shard_map
 
 from . import ref as _ref
@@ -67,7 +65,7 @@ def _per_shard(kernel, mesh, batched_in, batched_out):
     return shard_map(kernel, mesh, specs(batched_in), specs(batched_out))
 
 
-def _with_ref_vjp(kernel, reference, name: str):
+def _with_ref_vjp(kernel, reference):
     """``kernel`` forward, the VJP of the jnp ``reference`` backward."""
 
     @jax.custom_vjp
@@ -78,8 +76,7 @@ def _with_ref_vjp(kernel, reference, name: str):
         return kernel(*args), args
 
     def bwd(args, g):
-        with kernel_span(f"{name}[ref_vjp]"):
-            return jax.vjp(reference, *args)[1](g)
+        return jax.vjp(reference, *args)[1](g)
 
     f.defvjp(fwd, bwd)
     return f
@@ -91,18 +88,11 @@ def _with_ref_vjp(kernel, reference, name: str):
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None, impl=None):
-    B, S, H, hd = q.shape
-    T = k.shape[1]
-    flops = 4 * B * H * S * T * hd // (2 if causal else 1)
-    bytes_accessed = sum(int(np.prod(t.shape)) * t.dtype.itemsize for t in (q, k, v)) * 2
-    with kernel_span("flash_attention", (B, H, S), flops, bytes_accessed):
-        if _impl(impl) == "pallas":
-            from .flash_attention import flash_attention_pallas
+    if _impl(impl) == "pallas":
+        from .flash_attention import flash_attention_pallas
 
-            return flash_attention_pallas(
-                q, k, v, causal=causal, window=window, interpret=_interpret()
-            )
-        return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+        return flash_attention_pallas(q, k, v, causal=causal, window=window, interpret=_interpret())
+    return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -111,22 +101,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
 
 
 def rglru(x, r, i, lam, h0=None, *, impl=None):
-    B, S, C = x.shape
-    flops = 6 * B * S * C
-    nbytes = 3 * B * S * C * x.dtype.itemsize
-    with kernel_span("rglru_scan", (B, S, C), flops, nbytes):
-        if _impl(impl) == "pallas":
-            from .rglru_scan import rglru_pallas
+    if _impl(impl) == "pallas":
+        from .rglru_scan import rglru_pallas
 
-            if h0 is None:
-                h0 = jnp.zeros((B, C), jnp.float32)
-            f = _with_ref_vjp(
-                functools.partial(rglru_pallas, interpret=_interpret()),
-                _ref.rglru_ref,
-                "rglru_scan",
-            )
-            return f(x, r, i, lam, h0)
-        return _ref.rglru_ref(x, r, i, lam, h0=h0)
+        if h0 is None:
+            B, _, C = x.shape
+            h0 = jnp.zeros((B, C), jnp.float32)
+        f = _with_ref_vjp(functools.partial(rglru_pallas, interpret=_interpret()), _ref.rglru_ref)
+        return f(x, r, i, lam, h0)
+    return _ref.rglru_ref(x, r, i, lam, h0=h0)
 
 
 def rglru_step(h, x_t, r_t, i_t, lam):
@@ -139,26 +122,20 @@ def rglru_step(h, x_t, r_t, i_t, lam):
 
 
 def ssd(x, dt, A_log, Bm, Cm, D, *, chunk: int = 64, mesh=None, impl=None):
-    B, S, H, P = x.shape
-    N = Bm.shape[-1]
-    flops = B * S * H * (2 * P * N * 3 + 2 * chunk * P)  # states + intra approx
-    nbytes = (x.size + Bm.size * 2) * x.dtype.itemsize * 2
-    with kernel_span("ssd_scan", (B, H, S // chunk), flops, nbytes):
-        if _impl(impl) == "pallas":
-            from .ssd_scan import ssd_pallas
+    if _impl(impl) == "pallas":
+        from .ssd_scan import ssd_pallas
 
-            f = _with_ref_vjp(
-                _per_shard(
-                    functools.partial(ssd_pallas, chunk=chunk, interpret=_interpret()),
-                    mesh,
-                    (True, True, False, True, True, False),
-                    (True, True),
-                ),
-                functools.partial(_ref.ssd_ref, chunk=chunk),
-                "ssd_scan",
-            )
-            return f(x, dt, A_log, Bm, Cm, D)
-        return _ref.ssd_ref(x, dt, A_log, Bm, Cm, D, chunk=chunk)
+        f = _with_ref_vjp(
+            _per_shard(
+                functools.partial(ssd_pallas, chunk=chunk, interpret=_interpret()),
+                mesh,
+                (True, True, False, True, True, False),
+                (True, True),
+            ),
+            functools.partial(_ref.ssd_ref, chunk=chunk),
+        )
+        return f(x, dt, A_log, Bm, Cm, D)
+    return _ref.ssd_ref(x, dt, A_log, Bm, Cm, D, chunk=chunk)
 
 
 def ssd_step(state, x_t, dt_t, A_log, B_t, C_t, D):

@@ -4,7 +4,10 @@ The engine owns a fixed slot batch (decode efficiency demands static shapes
 on TPU).  Requests queue; a slot is (re)filled by running prefill for the
 incoming prompt and splicing its cache row into the live batch cache; every
 ``step()`` decodes one token for all active slots.  Both phases run through
-THAPI ``prefill``/``decode_step`` spans — the serving tally of §4.3.
+THAPI ``prefill``/``decode_step`` spans — the serving tally of §4.3 — inside
+one ``engine_step`` span around the whole step; each admitted request's time
+in the queue is a ``queue_wait`` span, and the token readback a D2H
+``memcpy``.
 
 The decode step is a TracedJit with explicit cache shardings (batch over the
 data axes, heads over model), donated cache — the same artifact the dry-run
@@ -22,7 +25,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.interception import TracedJit, decode_step_span, prefill_span
+from repro.core.clock import now
+from repro.core.interception import (
+    TracedJit,
+    decode_step_span,
+    engine_step_span,
+    prefill_span,
+    record_queue_wait,
+    traced_device_get,
+)
 from repro.models import Model, ShapeSpec
 from repro.models.param import axes as spec_axes, init as spec_init, shapes as spec_shapes
 from repro.sharding import Partitioner
@@ -44,6 +55,8 @@ class Request:
     prompt: np.ndarray  # [S] int32
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    #: trace-clock stamp of ``submit()``: where its ``queue_wait`` begins
+    t_submit: int = 0
 
 
 class ServeEngine:
@@ -111,23 +124,29 @@ class ServeEngine:
         self.completed: List[Request] = []
         self._tok = jnp.zeros((B,), jnp.int32)
         self._prefill_jits: Dict[int, TracedJit] = {}
+        self._steps = 0
 
     # -- request intake -----------------------------------------------------------
     def submit(self, prompt: np.ndarray) -> Request:
-        r = Request(rid=next(self._rid), prompt=np.asarray(prompt, np.int32))
+        r = Request(rid=next(self._rid), prompt=np.asarray(prompt, np.int32), t_submit=now())
         self.queue.append(r)
         return r
 
-    def _fill_slots(self) -> None:
+    def _fill_slots(self) -> int:
+        """Admit queued requests into free slots; returns how many."""
+        admitted = 0
         for i, slot in enumerate(self.slots):
             if slot is not None or not self.queue:
                 continue
             r = self.queue.pop(0)
             self._prefill_into(i, r)
             self.slots[i] = r
+            admitted += 1
+        return admitted
 
     def _prefill_into(self, slot: int, r: Request) -> None:
         """Prefill a single prompt, splice its cache row into the live batch."""
+        record_queue_wait(r.rid, r.t_submit)
         toks = r.prompt[None, :]
         with prefill_span(r.rid, 1, int(toks.shape[1])):
             batch = {"tokens": jnp.asarray(toks)}
@@ -168,7 +187,17 @@ class ServeEngine:
     # -- decode loop -----------------------------------------------------------------
     def step(self) -> int:
         """One batched decode step; returns #active slots."""
-        self._fill_slots()
+        busy = sum(s is not None for s in self.slots)
+        with engine_step_span(self._steps, busy) as sp:
+            self._steps += 1
+            admitted = self._fill_slots()
+            n = self._decode_active()
+            sp.outs["admitted"] = admitted
+            sp.outs["tokens_out"] = admitted + n
+        return n
+
+    def _decode_active(self) -> int:
+        """Decode one token for every active slot; returns how many."""
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return 0
@@ -184,7 +213,7 @@ class ServeEngine:
             self.adaptive.tick(engine=self)
         if self.cluster_adaptive is not None:
             self.cluster_adaptive.tick()
-        host = np.asarray(nxt)
+        host = traced_device_get(nxt)
         for i in active:
             r = self.slots[i]
             r.out_tokens.append(int(host[i]))

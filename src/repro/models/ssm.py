@@ -15,6 +15,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import ops as kops
 
@@ -159,10 +160,19 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
     B = token.shape[0]
     x = embed(params["embed"], token[:, None])
 
-    def body(h, inputs):
-        pl, conv_st, ssm_st = inputs
+    # The cache leaves are loop-carried and updated one layer slice at a time,
+    # in place in the donated cache (stacked scan outputs would be a fresh
+    # [L, ...] buffer that XLA copies into the cache after the loop).  Each
+    # layer's conv tail is written at the top of the next iteration, the last
+    # one after the loop: the old tail is read by several fused ops, and XLA
+    # copies the whole buffer unless all of them precede its update.
+    def body(carry, pl):
+        h, conv, state, tail, l = carry
+        conv = lax.dynamic_update_index_in_dim(conv, tail, jnp.maximum(l - 1, 0), 0)
+        conv_st = lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
         hn = rmsnorm(h, pl["ln"]["w"])
         z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn, conv_state=conv_st)
+        ssm_st = lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
         y, ssm_new = kops.ssd_step(
             ssm_st.astype(jnp.float32),
             xs[:, 0].reshape(B, nh, cfg.ssm.head_dim),
@@ -175,9 +185,13 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         y = y.reshape(B, 1, di)
         y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"])
         h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
-        return h, (conv_tail, ssm_new.astype(h.dtype))
+        state = lax.dynamic_update_index_in_dim(state, ssm_new.astype(state.dtype), l, 0)
+        return (h, conv, state, conv_tail.astype(conv.dtype), l + 1), None
 
-    x, (convs, states) = model_scan(cfg, body, x, (params["blocks"], cache["conv"], cache["state"]))
+    conv = cache["conv"]
+    init = (x, conv, cache["state"], conv[0], jnp.int32(0))
+    (x, conv, state, tail, _), _ = model_scan(cfg, body, init, params["blocks"])
+    conv = lax.dynamic_update_index_in_dim(conv, tail, conv.shape[0] - 1, 0)
     x = rmsnorm(x, params["ln_f"]["w"])
     logits = unembed(cfg, params["embed"], x)
-    return logits, {"conv": convs, "state": states, "len": cache["len"] + 1}
+    return logits, {"conv": conv, "state": state, "len": cache["len"] + 1}

@@ -7,6 +7,7 @@ exercised only via the dry-run (ShapeDtypeStructs, no allocation).
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,6 +141,45 @@ def test_ssm_decode_matches_prefill_continuation(rng):
     np.testing.assert_allclose(
         np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
     )
+
+
+def test_ssm_donated_decode_steps_match_prefill(rng):
+    """Three donated decode steps give the logits and cache of a prefill over
+    the prompt extended by the same tokens."""
+    cfg = get_config("mamba2-1.3b").smoke()
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(3))
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 8)), jnp.int32)
+    step = jax.jit(m.decode_step, donate_argnums=(1,))
+    _, cache = m.prefill(params, {"tokens": toks[:, :5]}, 16)
+    for t in range(5, 8):
+        step_logits, cache = step(params, cache, {"token": toks[:, t]})
+        full_logits, full_cache = m.prefill(params, {"tokens": toks[:, : t + 1]}, 16)
+        np.testing.assert_allclose(
+            np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
+        )
+    for k in ("conv", "state", "len"):
+        np.testing.assert_allclose(
+            np.asarray(full_cache[k]), np.asarray(cache[k]), rtol=2e-4, atol=2e-4, err_msg=k
+        )
+
+
+@pytest.mark.parametrize("leaf", ["state", "conv"])
+def test_ssm_decode_updates_cache_in_place(leaf):
+    """With the cache donated, the compiled decode step writes each layer's
+    slice into the cache buffer: no whole-leaf copy, nor a whole-leaf
+    broadcast initialising a fresh buffer."""
+    cfg = get_config("mamba2-1.3b").smoke()
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    cache = spec_init(m.cache_specs(SMOKE_DECODE), jax.random.PRNGKey(1), cfg.dtype)
+    token = {"token": jnp.zeros((SMOKE_DECODE.global_batch,), jnp.int32)}
+    step = jax.jit(m.decode_step, donate_argnums=(1,))
+    text = step.lower(params, cache, token).compile().as_text()
+    dims = ",".join(map(str, cache[leaf].shape))
+    whole = re.compile(r"= \w+\[%s\]\S* (copy|copy-start|broadcast)\(" % dims)
+    offenders = [line.strip() for line in text.splitlines() if whole.search(line)]
+    assert not offenders, offenders
 
 
 def test_hybrid_decode_matches_prefill_continuation(rng):

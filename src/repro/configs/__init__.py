@@ -22,6 +22,7 @@ ARCHS: List[str] = [
     "kimi-k2-1t-a32b",
     "mamba2-1.3b",
     "llava-next-34b",
+    "granite-4.0-h-small",
 ]
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

@@ -605,6 +605,29 @@ def serving_model() -> APIModel:
     )
 
 
+def routing_model() -> APIModel:
+    """ust_repro:moe_route — what the held experts of a device computed in
+    one prefill or decode step: one fused pair per step, recorded when the
+    step's tokens are read back (the counts come in the same readback).
+
+    ``pairs``: token–expert pairs whose expert is held here, over all
+    layers; ``max_load``: Σ over layers of the largest held expert's pairs;
+    ``held``: experts held in each layer.  A trailing :class:`APIModel`, so
+    every earlier event id stays the same.
+    """
+    return APIModel(
+        provider="ust_repro",
+        apis=(
+            APISpec(
+                "moe_route",
+                params=(P("decode", "bool"), P("held", "u32")),
+                result=P("status", "u32"),
+                meta=(("OutScalar", P("pairs", "u64")), ("OutScalar", P("max_load", "u64"))),
+            ),
+        ),
+    )
+
+
 def builtin_models() -> Tuple[APIModel, ...]:
     return (
         framework_model(),
@@ -615,6 +638,7 @@ def builtin_models() -> Tuple[APIModel, ...]:
         user_model(),
         remediation_model(),  # appended models keep earlier eids stable
         serving_model(),
+        routing_model(),
     )
 
 

@@ -93,6 +93,7 @@ class _TP:
         self.alloc_pair = p["ust_jaxrt:alloc"]
         self.poll_pair = p["ust_repro:poll_ready"]
         self.queue_wait_pair = p["ust_repro:queue_wait"]
+        self.moe_route_pair = p["ust_repro:moe_route"]
         self.kernel_span = r["ust_kernel:launch_span"]
         self.transfer_span = r["ust_kernel:transfer_span"]
         self.coll = {
@@ -445,3 +446,11 @@ def record_queue_wait(request_id: int, since_ns: int) -> None:
     tr = active_tracer()
     if tr is not None and tr.selected and tr.fidelity != "off":
         tp().queue_wait_pair(request_id, since_ns, 0)
+
+
+def record_moe_route(decode: bool, pairs: int, max_load: int, held: int) -> None:
+    """One ``moe_route`` pair for a prefill or decode step whose route
+    counts were just read back."""
+    tr = active_tracer()
+    if tr is not None and tr.selected and tr.fidelity != "off":
+        tp().moe_route_pair(decode, held, now(), 0, pairs, max_load)

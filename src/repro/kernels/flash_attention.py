@@ -92,7 +92,7 @@ def _largest_divisor(n: int, cap: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k", "interpret")
+    jax.jit, static_argnames=("causal", "window", "scale", "blk_q", "blk_k", "interpret")
 )
 def flash_attention_pallas(
     q,
@@ -101,6 +101,7 @@ def flash_attention_pallas(
     *,
     causal: bool = True,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
     blk_q: int = 128,
     blk_k: int = 128,
     interpret: bool = False,
@@ -120,7 +121,7 @@ def flash_attention_pallas(
         causal=causal,
         window=window,
         q_offset=T - S,
-        scale=1.0 / (hd**0.5),
+        scale=1.0 / (hd**0.5) if scale is None else scale,
     )
     out = pl.pallas_call(
         kern,

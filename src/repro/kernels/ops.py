@@ -87,12 +87,17 @@ def _with_ref_vjp(kernel, reference):
 # ---------------------------------------------------------------------------
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None, impl=None):
+def flash_attention(
+    q, k, v, *, causal: bool = True, window: Optional[int] = None, scale: Optional[float] = None, impl=None
+):
+    """Scores scaled by ``scale``, 1/√head_dim when None."""
     if _impl(impl) == "pallas":
         from .flash_attention import flash_attention_pallas
 
-        return flash_attention_pallas(q, k, v, causal=causal, window=window, interpret=_interpret())
-    return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+        return flash_attention_pallas(
+            q, k, v, causal=causal, window=window, scale=scale, interpret=_interpret()
+        )
+    return _ref.flash_attention_ref(q, k, v, causal=causal, window=window, scale=scale)
 
 
 # ---------------------------------------------------------------------------

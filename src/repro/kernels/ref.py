@@ -19,16 +19,16 @@ import jax.numpy as jnp
 
 
 def flash_attention_ref(
-    q, k, v, *, causal: bool = True, window: Optional[int] = None
+    q, k, v, *, causal: bool = True, window: Optional[int] = None, scale: Optional[float] = None
 ):
-    """Materialized-scores attention. q:[B,S,H,d] k/v:[B,T,Kv,d] → [B,S,H,d]."""
+    """Materialized-scores attention. q:[B,S,H,d] k/v:[B,T,Kv,d] → [B,S,H,d];
+    scores scaled by ``scale`` (default 1/√d)."""
     B, S, H, hd = q.shape
     T, Kv = k.shape[1], k.shape[2]
     G = H // Kv
     qg = q.reshape(B, S, Kv, G, hd)
-    s = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32) / jnp.sqrt(
-        jnp.asarray(hd, jnp.float32)
-    )
+    s = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
+    s = s / jnp.sqrt(jnp.asarray(hd, jnp.float32)) if scale is None else s * scale
     qi = jnp.arange(S)[:, None] + (T - S)
     kj = jnp.arange(T)[None, :]
     mask = jnp.ones((S, T), bool)

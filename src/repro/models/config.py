@@ -21,6 +21,15 @@ class MoEConfig:
     capacity_factor: float = 1.25
     #: router jitter/aux-loss weight (load balancing, standard switch loss)
     aux_loss_weight: float = 0.01
+    #: width of a shared SwiGLU expert beside the routed ones (0: none)
+    d_ff_shared: int = 0
+    #: ids of the experts this device holds of each layer, the router still
+    #: choosing among all ``num_experts`` (None: all of them)
+    experts_held: Optional[Tuple[int, ...]] = None
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        return tuple(range(self.num_experts)) if self.experts_held is None else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +41,8 @@ class SSMConfig:
     chunk: int = 256
     #: groups for B/C projections (Mamba2 'ngroups')
     n_groups: int = 1
+    #: a bias on the depthwise conv's channels
+    conv_bias: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,7 +68,7 @@ class EncDecConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
+    family: str  # dense | moe | ssm | hybrid | mamba_hybrid | audio | vlm
     num_layers: int
     d_model: int
     num_heads: int
@@ -90,6 +101,18 @@ class ModelConfig:
     #: XLA cost_analysis counts a while-loop body once, so the dry-run
     #: compiles unrolled k/2k-depth variants and extrapolates linearly)
     scan_unroll: bool = False
+
+    #: mixer of each layer, "mamba" or "attention" (family mamba_hybrid)
+    layer_types: Tuple[str, ...] = ()
+    #: Granite's scalings: the embedding is multiplied, each residual branch
+    #: multiplied, the logits divided; attention scores multiplied by
+    #: ``attention_multiplier`` (None: 1/√head_dim)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    #: RMSNorm epsilon
+    norm_eps: float = 1e-6
 
     # -- §Perf hillclimb knobs (beyond-paper optimizations) -------------------
     #: pad attention head counts up to this multiple so they shard over the
@@ -159,6 +182,8 @@ class ModelConfig:
             g = self.ssm.n_groups
             in_proj = d * (2 * di + 2 * g * self.ssm.d_state + nh)
             total += L * (in_proj + di * d + self.ssm.d_conv * (di + 2 * g * self.ssm.d_state) + 2 * nh + d)
+        elif self.family == "mamba_hybrid":
+            total += self._mamba_hybrid_layers() + d
         elif self.family == "hybrid":
             assert self.rglru is not None
             w = self.rglru.width or d
@@ -182,8 +207,27 @@ class ModelConfig:
             total += L * per_layer
         return total
 
+    def _mamba_hybrid_layers(self) -> int:
+        """Parameters of the mamba_hybrid family's layers, the held experts
+        of each MoE (the final norm and the embedding not counted)."""
+        d, hd, s, m = self.d_model, self.head_dim_, self.ssm, self.moe
+        di = s.expand * d
+        nh, GN = di // s.head_dim, s.n_groups * s.d_state
+        conv_ch = di + 2 * GN
+        mamba = d * (2 * di + 2 * GN + nh) + (s.d_conv + s.conv_bias) * conv_ch + 3 * nh + di + di * d
+        attn = 2 * d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd
+        ffn = d * m.num_experts + len(m.held) * 3 * d * m.d_ff_expert + 3 * d * m.d_ff_shared
+        n_attn = sum(t == "attention" for t in self.layer_types[: self.num_layers])
+        n_mamba = self.num_layers - n_attn
+        return n_mamba * mamba + n_attn * attn + self.num_layers * (ffn + 2 * d)
+
     def active_params(self) -> int:
-        """Active parameters per token (MoE: top_k experts only)."""
+        """Active parameters per token (MoE: top_k experts only; with held
+        experts, the top_k's expected share of them)."""
+        if self.family == "mamba_hybrid":
+            m = self.moe
+            routed = self.num_layers * 3 * self.d_model * m.d_ff_expert
+            return self.num_params() - routed * len(m.held) + routed * m.top_k * len(m.held) // m.num_experts
         if self.family != "moe":
             return self.num_params()
         assert self.moe is not None
@@ -223,9 +267,16 @@ class ModelConfig:
             remat="none",
         )
         if self.moe:
-            kw["moe"] = MoEConfig(num_experts=8, top_k=2, d_ff_expert=32)
+            kw["moe"] = MoEConfig(
+                num_experts=8, top_k=2, d_ff_expert=32, d_ff_shared=64 if self.moe.d_ff_shared else 0
+            )
         if self.ssm:
-            kw["ssm"] = SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=16, chunk=8)
+            kw["ssm"] = SSMConfig(
+                d_state=16, d_conv=4, expand=2, head_dim=16, chunk=8, conv_bias=self.ssm.conv_bias
+            )
+        if self.layer_types:  # both mixer kinds, in three runs
+            kw["layer_types"] = ("mamba", "mamba", "attention", "mamba", "mamba")
+            kw["num_layers"] = 5
         if self.rglru:
             kw["rglru"] = RGLRUConfig(width=64, pattern=self.rglru.pattern, local_window=16)
         if self.encdec:

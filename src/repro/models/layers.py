@@ -51,7 +51,7 @@ def norm_spec(cfg: ModelConfig, stacked: Optional[int] = None) -> dict:
 def apply_norm(cfg: ModelConfig, p: dict, x):
     if cfg.norm == "layernorm":
         return layernorm(x, p["w"], p["b"])
-    return rmsnorm(x, p["w"])
+    return rmsnorm(x, p["w"], cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -90,6 +90,7 @@ def attend(
     window: Optional[int] = None,
     q_offset: Optional[jnp.ndarray] = None,  # absolute position of q[.,0]
     kv_len: Optional[jnp.ndarray] = None,  # valid prefix length of k/v
+    scale: Optional[float] = None,  # score multiplier; None: 1/√hd
 ):
     """Grouped-query attention with unified masking.
 
@@ -102,7 +103,10 @@ def attend(
     G = H // Kv
     qg = q.reshape(B, S, Kv, G, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    else:
+        scores = scores * scale
 
     qi = jnp.arange(S)[:, None]  # [S, 1]
     kj = jnp.arange(T)[None, :]  # [1, T]
@@ -323,14 +327,17 @@ def kv_cache_specs(cfg: ModelConfig, batch: int, cache_len: int, layers: int) ->
     }
 
 
-def cache_update(cache_k, cache_v, k_new, v_new, lengths, window: Optional[int] = None):
-    """Insert one decode step's K/V at position ``lengths`` (ring for SWA)."""
-    T = cache_k.shape[1]
+def cache_update(cache_k, cache_v, k_new, v_new, lengths, window: Optional[int] = None, layer=None):
+    """Insert one decode step's K/V at position ``lengths`` (ring for SWA).
+    With ``layer``, the caches are stacked [layers, B, T, ...] and the step
+    is written into that layer's slice, in place."""
+    lead = () if layer is None else (layer,)
+    T = cache_k.shape[len(lead) + 1]
     if window is not None:
         idx = lengths % T
     else:
         idx = jnp.minimum(lengths, T - 1)
-    b = jnp.arange(cache_k.shape[0])
-    cache_k = cache_k.at[b, idx].set(k_new[:, 0])
-    cache_v = cache_v.at[b, idx].set(v_new[:, 0])
+    b = jnp.arange(cache_k.shape[len(lead)])
+    cache_k = cache_k.at[lead + (b, idx)].set(k_new[:, 0])
+    cache_v = cache_v.at[lead + (b, idx)].set(v_new[:, 0])
     return cache_k, cache_v

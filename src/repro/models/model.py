@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import encdec, hybrid, moe, ssm, transformer
+from . import encdec, hybrid, mamba_hybrid, moe, ssm, transformer
 from .config import ModelConfig, ShapeSpec
 from .layers import xent_loss
 from .param import Spec, axes as spec_axes, init as spec_init, shapes as spec_shapes
@@ -29,6 +29,7 @@ _FAMILY = {
     "moe": moe,
     "ssm": ssm,
     "hybrid": hybrid,
+    "mamba_hybrid": mamba_hybrid,
     "audio": encdec,
 }
 
@@ -57,7 +58,7 @@ class Model:
         if self.cfg.family == "moe":
             out, aux = self.mod.forward_train(self.cfg, params, batch, mesh=self.mesh)
             return out, aux
-        if self.cfg.family == "ssm":  # the SSD kernel runs per data shard
+        if self.cfg.family in ("ssm", "mamba_hybrid"):  # the SSD kernel runs per data shard
             out = self.mod.forward_train(self.cfg, params, batch, mesh=self.mesh)
         else:
             out = self.mod.forward_train(self.cfg, params, batch)
@@ -75,12 +76,25 @@ class Model:
         return total, {"ce": ce, "aux": aux}
 
     # -- serving -----------------------------------------------------------------
-    def prefill(self, params, batch, cache_len: int):
+    @property
+    def routes(self) -> bool:
+        """Whether prefill and decode can also return the step's route counts
+        (``route=True``): token–expert pairs on the held experts, Σ over
+        layers of the largest held expert's pairs, experts held (int32 [3])."""
+        return self.cfg.family == "mamba_hybrid"
+
+    def prefill(self, params, batch, cache_len: int, route: bool = False):
+        if self.routes:
+            out = self.mod.prefill(self.cfg, params, batch, cache_len, mesh=self.mesh)
+            return out if route else out[:2]
         if self.cfg.family in ("moe", "ssm"):
             return self.mod.prefill(self.cfg, params, batch, cache_len, mesh=self.mesh)
         return self.mod.prefill(self.cfg, params, batch, cache_len)
 
-    def decode_step(self, params, cache, batch):
+    def decode_step(self, params, cache, batch, route: bool = False):
+        if self.routes:
+            out = self.mod.decode_step(self.cfg, params, cache, batch)
+            return out if route else out[:2]
         if self.cfg.family == "moe":
             return self.mod.decode_step(self.cfg, params, cache, batch, mesh=self.mesh)
         return self.mod.decode_step(self.cfg, params, cache, batch)

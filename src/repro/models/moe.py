@@ -15,6 +15,11 @@ Two dispatch paths:
     psum combine. No a2a on the latency-critical decode path.
 Fallback ``_moe_dense`` (all experts, masked combine) is the oracle for
 tests and the single-device smoke path.
+
+``held_moe`` is one device's share of an expert-parallel layer whose other
+experts live on other devices: it routes over all experts and computes the
+part of the result that its held experts give, with no capacity and so no
+drops (``mamba_hybrid``).
 """
 
 from __future__ import annotations
@@ -143,6 +148,61 @@ def _moe_dense(cfg: ModelConfig, p: dict, xt):
         .add(w)
     )
     return jnp.einsum("ted,te->td", y_all, combine), aux
+
+
+def _held_index(cfg: ModelConfig) -> np.ndarray:
+    """Position of each expert id among the held ones; ``len(held)`` for an
+    expert held elsewhere."""
+    held = cfg.moe.held
+    out = np.full((cfg.moe.num_experts,), len(held), np.int32)
+    out[np.asarray(held, np.int64)] = np.arange(len(held), dtype=np.int32)
+    return out
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """One SwiGLU expert on tokens x [T, d]."""
+    return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up), w_down)
+
+
+def held_moe(cfg: ModelConfig, p: dict, xt, grouped: bool):
+    """The held experts' part of the MoE on tokens xt [T, d], plus the shared
+    expert where the layer has one.
+
+    The router chooses ``top_k`` of all ``num_experts`` with the softmax over
+    the chosen logits; only pairs whose expert is held are computed, every
+    one of them.  ``grouped``: the pairs sorted by expert and run as one
+    grouped matmul (``lax.ragged_dot``), each expert on its own tokens; else
+    every held expert on every token, weighted by its gate (0 where not
+    chosen), which at a decode batch costs no more than reading the weights.
+    ``p``: ``router`` [d, E], ``w_gate``/``w_up`` [Eh, d, f], ``w_down``
+    [Eh, f, d], ``shared_*``.  Returns (out [T, d], pairs per held expert [Eh]).
+    """
+    T = xt.shape[0]
+    Eh = len(cfg.moe.held)
+    w, idx, _ = _router(cfg, p["router"], xt)
+    local = jnp.asarray(_held_index(cfg))[idx]  # [T, k]; Eh: held elsewhere
+    load = jnp.bincount(local.reshape(-1), length=Eh + 1)[:Eh]
+    if grouped:
+        k = idx.shape[1]
+        order = jnp.argsort(local.reshape(-1))  # held pairs first, by expert
+        xs = xt[order // k]
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], load)) * jax.lax.ragged_dot(
+            xs, p["w_up"], load
+        )
+        y = jax.lax.ragged_dot(h, p["w_down"], load)  # rows past the groups: unused
+        y = y[jnp.argsort(order)].reshape(T, k, -1)  # back to (token, choice)
+        y = jnp.where((local < Eh)[..., None], y.astype(jnp.float32) * w[..., None], 0.0)
+        out = jnp.sum(y, axis=1)
+    else:
+        gate = jnp.zeros((T, Eh + 1), w.dtype).at[jnp.arange(T)[:, None], local].add(w)[:, :Eh]
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", xt, p["w_gate"])) * jnp.einsum(
+            "td,edf->tef", xt, p["w_up"]
+        )
+        out = jnp.einsum("tef,efd->td", h * gate[:, :, None], p["w_down"],
+                         preferred_element_type=jnp.float32)
+    if cfg.moe.d_ff_shared:
+        out = out + swiglu(xt, p["shared_gate"], p["shared_up"], p["shared_down"])
+    return out.astype(xt.dtype), load
 
 
 def _local_expert_compute(cfg, p_local, xt, w, idx, ep: int, my_shard, capacity: int):

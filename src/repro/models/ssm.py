@@ -48,6 +48,7 @@ def specs(cfg: ModelConfig) -> dict:
             "wC": Spec((L, d, G * N), ("layers", "embed", "state")),
             "wdt": Spec((L, d, nh), ("layers", "embed", "ssm_heads")),
             "conv_w": Spec((L, K, conv_ch), ("layers", "conv", "channels")),
+            **({"conv_b": Spec((L, conv_ch), ("layers", "channels"), "zeros")} if cfg.ssm.conv_bias else {}),
             "A_log": Spec((L, nh), ("layers", "ssm_heads"), "ssm_a"),
             "D": Spec((L, nh), ("layers", "ssm_heads"), "ones"),
             "dt_bias": Spec((L, nh), ("layers", "ssm_heads"), "ssm_dt"),
@@ -68,6 +69,8 @@ def _mix(cfg: ModelConfig, p: dict, h, conv_state=None):
     dtl = jnp.einsum("bsd,dh->bsh", h, p["wdt"])
     xbc = jnp.concatenate([xs, Bm, Cm], axis=-1)
     xbc, conv_tail = kops.causal_conv1d(xbc, p["conv_w"], state=conv_state)
+    if cfg.ssm.conv_bias:
+        xbc = xbc + p["conv_b"]
     xbc = jax.nn.silu(xbc)
     xs, Bm, Cm = jnp.split(xbc, [di, di + G * N], axis=-1)
     dt = jax.nn.softplus(dtl.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
@@ -77,7 +80,7 @@ def _mix(cfg: ModelConfig, p: dict, h, conv_state=None):
 def block(cfg: ModelConfig, p: dict, x, mesh=None):
     di, nh, G, N, _ = _dims(cfg)
     B, S, _ = x.shape
-    h = rmsnorm(x, p["ln"]["w"])
+    h = rmsnorm(x, p["ln"]["w"], cfg.norm_eps)
     z, xs, Bm, Cm, dt, _ = _mix(cfg, p, h)
     y, _ = kops.ssd(
         xs.reshape(B, S, nh, cfg.ssm.head_dim),
@@ -90,7 +93,7 @@ def block(cfg: ModelConfig, p: dict, x, mesh=None):
         mesh=mesh,
     )
     y = y.reshape(B, S, di)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"])
+    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"], cfg.norm_eps)
     return x + jnp.einsum("bse,ed->bsd", y, p["wo"])
 
 
@@ -98,7 +101,7 @@ def forward_train(cfg: ModelConfig, params: dict, batch: dict, mesh=None):
     x = embed(params["embed"], batch["tokens"])
     body = _remat(cfg, lambda h, pl: (block(cfg, pl, h, mesh), None))
     x, _ = model_scan(cfg, body, x, params["blocks"])
-    x = rmsnorm(x, params["ln_f"]["w"])
+    x = rmsnorm(x, params["ln_f"]["w"], cfg.norm_eps)
     return unembed(cfg, params["embed"], x)
 
 
@@ -130,7 +133,7 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=No
     x = embed(params["embed"], tokens)
 
     def body(h, pl):
-        hn = rmsnorm(h, pl["ln"]["w"])
+        hn = rmsnorm(h, pl["ln"]["w"], cfg.norm_eps)
         z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn)
         y, st = kops.ssd(
             xs.reshape(B, S, nh, cfg.ssm.head_dim),
@@ -143,12 +146,12 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=No
             mesh=mesh,
         )
         y = y.reshape(B, S, di)
-        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"])
+        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"], cfg.norm_eps)
         h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
         return h, (conv_tail, st.astype(x.dtype))
 
     x, (convs, states) = model_scan(cfg, _remat(cfg, body), x, params["blocks"])
-    x = rmsnorm(x, params["ln_f"]["w"])
+    x = rmsnorm(x, params["ln_f"]["w"], cfg.norm_eps)
     logits = unembed(cfg, params["embed"], x[:, -1:])
     cache = {"conv": convs, "state": states, "len": jnp.full((B,), S, jnp.int32)}
     return logits, cache
@@ -170,7 +173,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         h, conv, state, tail, l = carry
         conv = lax.dynamic_update_index_in_dim(conv, tail, jnp.maximum(l - 1, 0), 0)
         conv_st = lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
-        hn = rmsnorm(h, pl["ln"]["w"])
+        hn = rmsnorm(h, pl["ln"]["w"], cfg.norm_eps)
         z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn, conv_state=conv_st)
         ssm_st = lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
         y, ssm_new = kops.ssd_step(
@@ -183,7 +186,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
             pl["D"],
         )
         y = y.reshape(B, 1, di)
-        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"])
+        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"], cfg.norm_eps)
         h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
         state = lax.dynamic_update_index_in_dim(state, ssm_new.astype(state.dtype), l, 0)
         return (h, conv, state, conv_tail.astype(conv.dtype), l + 1), None
@@ -192,6 +195,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
     init = (x, conv, cache["state"], conv[0], jnp.int32(0))
     (x, conv, state, tail, _), _ = model_scan(cfg, body, init, params["blocks"])
     conv = lax.dynamic_update_index_in_dim(conv, tail, conv.shape[0] - 1, 0)
-    x = rmsnorm(x, params["ln_f"]["w"])
+    x = rmsnorm(x, params["ln_f"]["w"], cfg.norm_eps)
     logits = unembed(cfg, params["embed"], x)
     return logits, {"conv": conv, "state": state, "len": cache["len"] + 1}

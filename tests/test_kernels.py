@@ -71,6 +71,31 @@ def test_flash_attention_matches_softmax_definition():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("impl", ["pallas", "ops"])
+def test_flash_attention_non_default_scale(impl):
+    """A given ``scale`` replaces 1/√d (GQA, scale 1/d as in Granite's NoPE
+    attention): kernel, wrapper and reference against the literal softmax."""
+    from repro.kernels import ops
+
+    hd, scale = 32, 1.0 / 32
+    q, k, v = randn(2, 32, 4, hd, scale=4.0), randn(2, 32, 2, hd, scale=4.0), randn(2, 32, 2, hd)
+    kr, vr = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+    s = jnp.einsum("bshd,bthd->bhst", q, kr) * scale
+    s = jnp.where(np.tril(np.ones((32, 32), bool))[None, None], s, -1e30)
+    want = jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(s, -1), vr)
+    np.testing.assert_allclose(
+        np.asarray(ref.flash_attention_ref(q, k, v, scale=scale)), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
+    if impl == "pallas":
+        got = flash_attention_pallas(q, k, v, scale=scale, blk_q=16, blk_k=8, interpret=True)
+    else:
+        got = ops.flash_attention(q, k, v, scale=scale, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    # the default stays 1/√d
+    default = flash_attention_pallas(q, k, v, blk_q=16, blk_k=8, interpret=True)
+    assert not np.allclose(np.asarray(default), np.asarray(want), atol=1e-3)
+
+
 @settings(max_examples=10, deadline=None)
 @given(
     bq=st.sampled_from([8, 16, 32, 64]),

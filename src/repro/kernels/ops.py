@@ -24,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.jaxcompat import shard_map
@@ -143,8 +144,17 @@ def ssd(x, dt, A_log, Bm, Cm, D, *, chunk: int = 64, mesh=None, impl=None):
     return _ref.ssd_ref(x, dt, A_log, Bm, Cm, D, chunk=chunk)
 
 
-def ssd_step(state, x_t, dt_t, A_log, B_t, C_t, D):
-    return _ref.ssd_step_ref(state, x_t, dt_t, A_log, B_t, C_t, D)
+def ssd_step_inplace(state, l, x_t, dt_t, A_log, B_t, C_t, D, *, impl=None):
+    """One decode token through layer ``l`` of the stacked state
+    [L,B,H,P,N] → (y_t [B,H,P], state with that layer's slice replaced).
+    Loop-carried and donated, the state is updated in place on both paths."""
+    if _impl(impl) == "pallas":
+        from .ssd_step import ssd_step_pallas
+
+        return ssd_step_pallas(state, l, x_t, dt_t, A_log, B_t, C_t, D, interpret=_interpret())
+    st = lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
+    y, st = _ref.ssd_step_ref(st.astype(jnp.float32), x_t, dt_t, A_log, B_t, C_t, D)
+    return y, lax.dynamic_update_index_in_dim(state, st.astype(state.dtype), l, 0)
 
 
 def causal_conv1d(x, w, state=None):

@@ -241,9 +241,10 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
     cache = dict(cache)
     for kind, first, start, n in runs(cfg):
         if kind == "mamba":
-            # as ssm.decode_step: the state slice is read and written back by
-            # index; each conv tail is written at the top of the next
-            # iteration (the last after the run), after every read of the old
+            # as ssm.decode_step: the state is updated in place by layer index
+            # (``kops.ssd_step_inplace``); each conv tail is written at the top
+            # of the next iteration (the last after the run), after every read
+            # of the old buffer
 
             def body(carry, i, first=first, start=start):
                 h, conv, state, tail, route = carry
@@ -253,13 +254,11 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
                 hn = rmsnorm(h, p["ln"]["w"], cfg.norm_eps)
                 conv_st = lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
                 z, xs, Bm, Cm, dt, tail = ssm._mix(cfg, p, hn, conv_state=conv_st)
-                st = lax.dynamic_index_in_dim(state, j, 0, keepdims=False)
-                y, st = kops.ssd_step(
-                    st.astype(jnp.float32), xs[:, 0].reshape(B, nh, P), dt[:, 0], p["A_log"],
+                y, state = kops.ssd_step_inplace(
+                    state, j, xs[:, 0].reshape(B, nh, P), dt[:, 0], p["A_log"],
                     Bm[:, 0].reshape(B, G, N), Cm[:, 0].reshape(B, G, N), p["D"],
                 )
                 h = _residual(cfg, h, _mamba_out(cfg, p, y.reshape(B, 1, di), z))
-                state = lax.dynamic_update_index_in_dim(state, st.astype(state.dtype), j, 0)
                 h, route = _ffn(cfg, _at(params["moe"], first + i), h, False, route)
                 return (h, conv, state, tail.astype(conv.dtype), route), None
 

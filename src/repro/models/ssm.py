@@ -175,9 +175,9 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         conv_st = lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
         hn = rmsnorm(h, pl["ln"]["w"], cfg.norm_eps)
         z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn, conv_state=conv_st)
-        ssm_st = lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
-        y, ssm_new = kops.ssd_step(
-            ssm_st.astype(jnp.float32),
+        y, state = kops.ssd_step_inplace(
+            state,
+            l,
             xs[:, 0].reshape(B, nh, cfg.ssm.head_dim),
             dt[:, 0],
             pl["A_log"],
@@ -188,7 +188,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         y = y.reshape(B, 1, di)
         y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"], cfg.norm_eps)
         h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
-        state = lax.dynamic_update_index_in_dim(state, ssm_new.astype(state.dtype), l, 0)
         return (h, conv, state, conv_tail.astype(conv.dtype), l + 1), None
 
     conv = cache["conv"]

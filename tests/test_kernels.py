@@ -2,13 +2,15 @@
 always against the pure-jnp oracles in kernels/ref.py (interpret=True on CPU
 — the kernel body itself executes)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from tests.hypothesis_optional import given, settings, st
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rglru_scan import rglru_pallas
 from repro.kernels.ssd_scan import ssd_pallas
@@ -231,6 +233,72 @@ def test_ssd_state_continuation():
         np.asarray(jnp.concatenate([ya, yb], 1)), np.asarray(full), rtol=2e-4, atol=2e-4
     )
     np.testing.assert_allclose(np.asarray(sb), np.asarray(st_full), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Mamba2 SSD decode step, in place in the stacked state
+# ---------------------------------------------------------------------------
+
+
+def _ref_step_inplace(state, l, x, dt, A_log, Bt, Ct, D):
+    """The reference's path: the layer's slice read, stepped, written back."""
+    st_ = jax.lax.dynamic_index_in_dim(state, l, 0, keepdims=False)
+    y, st_ = ref.ssd_step_ref(st_.astype(jnp.float32), x, dt, A_log, Bt, Ct, D)
+    return y, jax.lax.dynamic_update_index_in_dim(state, st_.astype(state.dtype), l, 0)
+
+
+@pytest.mark.parametrize(
+    "L,B,H,P,N,G",
+    [
+        (3, 2, 64, 64, 16, 1),  # mamba2-1.3b's heads, the state cut to 16
+        (2, 2, 128, 64, 16, 1),  # granite's: twice the heads
+        (3, 8, 16, 32, 16, 1),  # 8 slots, as the code mix serves
+        (2, 2, 16, 32, 16, 2),  # two B/C groups
+    ],
+)
+def test_ssd_step_inplace_matches_reference(L, B, H, P, N, G):
+    """Layer after layer in a scan, as the decode step runs it, the kernel's
+    state equals the reference's bit for bit and y lies within one bf16 ulp
+    of it; a step of one layer leaves every other layer as it was.
+
+    Every f32 product of the update is exact on this data, so that the
+    order of evaluation cannot move a last bit: dt lies on a grid of 1/1024
+    (XLA associates the reference's dt·x·B differently by shape: on the CPU
+    x·(dt·B) with one group, (dt·x)·B with two), and the state holds signed
+    powers of two (the CPU compiler fuses h·exp(dt·A) + x·dt·B into one
+    multiply-add or not, depending on what else it fused)."""
+    rng = np.random.default_rng(7)
+    signs = rng.choice([-1.0, 1.0], size=(L, B, H, P, N))
+    state = jnp.asarray(signs * 2.0 ** rng.integers(-8, 3, size=(L, B, H, P, N)), jnp.bfloat16)
+    per_layer = (
+        jnp.asarray(rng.normal(size=(L, B, H, P)), jnp.bfloat16),
+        jnp.asarray(rng.integers(1, 128, size=(L, B, H)) / 1024, jnp.float32),
+        jnp.asarray(rng.uniform(0, 2, size=(L, H)), jnp.float32),
+        jnp.asarray(rng.normal(size=(L, B, G, N)), jnp.bfloat16),
+        jnp.asarray(rng.normal(size=(L, B, G, N)), jnp.bfloat16),
+        jnp.asarray(rng.uniform(0.5, 1.5, size=(L, H)), jnp.float32),
+    )
+    kernel = functools.partial(ops.ssd_step_inplace, impl="pallas")
+
+    def layers(step):
+        def body(carry, p):
+            st_, l = carry
+            y, st_ = step(st_, l, *p)
+            return (st_, l + 1), y
+
+        (st_, _), y = jax.jit(lambda s: jax.lax.scan(body, (s, jnp.int32(0)), per_layer))(state)
+        return np.asarray(st_.astype(jnp.float32)), np.asarray(y)
+
+    want_st, want_y = layers(_ref_step_inplace)
+    got_st, got_y = layers(kernel)
+    np.testing.assert_array_equal(got_st, want_st)
+    gap = np.abs(got_y.astype(np.float32) - want_y.astype(np.float32))
+    assert np.all(gap <= np.spacing(np.abs(want_y)).astype(np.float32))
+
+    one = np.asarray(jax.jit(kernel)(state, 1, *(p[1] for p in per_layer))[1].astype(jnp.float32))
+    before = np.asarray(state.astype(jnp.float32))
+    np.testing.assert_array_equal(np.delete(one, 1, axis=0), np.delete(before, 1, axis=0))
+    assert not np.array_equal(one[1], before[1])
 
 
 # ---------------------------------------------------------------------------

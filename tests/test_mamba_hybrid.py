@@ -12,6 +12,7 @@ the chunked SSD, the blocked attention, the grouped and dense MoE forms.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import re
 import sys
@@ -27,6 +28,7 @@ sys.path.insert(0, str(ROOT))
 
 from bench import reference_hybrid  # noqa: E402
 from bench.weights_hybrid import dims, make_weights, program_config  # noqa: E402
+from repro.kernels import ops  # noqa: E402
 from repro.models import Model, ShapeSpec  # noqa: E402
 from repro.models import mamba_hybrid, moe  # noqa: E402
 from repro.models.param import init as spec_init  # noqa: E402
@@ -177,6 +179,26 @@ def test_continuous_batching_gives_each_slot_its_own_logits(f32):
         cache, tok = batch_step(cache, tok)
     for _ in range(3):  # all three slots in flight
         cache, tok = batch_step(cache, tok)
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_donated_decode_steps_match_prefill(f32, impl, monkeypatch):
+    """Three donated decode steps give the logits and cache of a prefill over
+    the prompt extended by the same tokens; ``pallas`` updates the Mamba2
+    state in the decode kernel (interpreted on the CPU)."""
+    monkeypatch.setattr(ops, "ssd_step_inplace", functools.partial(ops.ssd_step_inplace, impl=impl))
+    z, model, w = f32
+    seq = jnp.asarray(np.random.default_rng(10).integers(0, z["V"], size=(2, 8)), jnp.int32)
+    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    _, cache = model.prefill(w, {"tokens": seq[:, :5]}, 16)
+    for t in range(5, 8):
+        logits, cache = step(w, cache, {"token": seq[:, t]})
+        want, want_cache = model.prefill(w, {"tokens": seq[:, : t + 1]}, 16)
+        np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(want[:, 0]), **TOL)
+    for k in want_cache:  # the chunked prefill scan against the steps: as test_models_smoke's
+        np.testing.assert_allclose(
+            np.asarray(cache[k]), np.asarray(want_cache[k]), rtol=2e-4, atol=2e-4, err_msg=k
+        )
 
 
 @pytest.fixture(scope="module")

@@ -7,6 +7,7 @@ exercised only via the dry-run (ShapeDtypeStructs, no allocation).
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -15,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config
+from repro.kernels import ops
 from repro.models import Model, ShapeSpec
 from repro.models.param import count as param_count, init as spec_init, shapes as spec_shapes
 
@@ -146,6 +148,17 @@ def test_ssm_decode_matches_prefill_continuation(rng):
 def test_ssm_donated_decode_steps_match_prefill(rng):
     """Three donated decode steps give the logits and cache of a prefill over
     the prompt extended by the same tokens."""
+    _donated_decode_steps_match_prefill(rng)
+
+
+def test_ssm_donated_decode_steps_through_the_kernel_match_prefill(rng, monkeypatch):
+    """The same with the decode step's state update in the Pallas kernel
+    (interpreted on the CPU), prefill on the reference."""
+    monkeypatch.setattr(ops, "ssd_step_inplace", functools.partial(ops.ssd_step_inplace, impl="pallas"))
+    _donated_decode_steps_match_prefill(rng)
+
+
+def _donated_decode_steps_match_prefill(rng):
     cfg = get_config("mamba2-1.3b").smoke()
     m = Model(cfg)
     params = m.init(jax.random.PRNGKey(3))
@@ -162,6 +175,9 @@ def test_ssm_donated_decode_steps_match_prefill(rng):
         np.testing.assert_allclose(
             np.asarray(full_cache[k]), np.asarray(cache[k]), rtol=2e-4, atol=2e-4, err_msg=k
         )
+    # at this init the state is about 1e-5 across: the same 2e-4, of its scale
+    want = np.asarray(full_cache["state"])
+    np.testing.assert_allclose(np.asarray(cache["state"]), want, rtol=2e-4, atol=2e-4 * np.abs(want).max())
 
 
 @pytest.mark.parametrize("leaf", ["state", "conv"])

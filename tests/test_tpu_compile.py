@@ -11,16 +11,21 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rglru_scan import rglru_pallas
 from repro.kernels.ssd_scan import ssd_pallas
+from repro.kernels.ssd_step import ssd_step_pallas
+from repro.models import Model, ShapeSpec
+from repro.models.param import shapes as spec_shapes
 
 
 @pytest.fixture(scope="module")
@@ -111,3 +116,44 @@ def test_kernel_gradient_compiles_for_v5e(one_chip, kernel, monkeypatch):
 
     text = _compile(jax.grad(loss, argnums=tuple(range(len(shapes)))), shapes, one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("L,H,G", [(48, 64, 1), (9, 128, 1), (4, 64, 8)])
+def test_ssd_step_compiles_for_v5e(one_chip, L, H, G):
+    """The decode kernel on a stacked state at 64 slots, heads of 64, state
+    128: mamba2-1.3b (48 layers of 64 heads) and granite-4.0-h-small (its
+    stage's nine Mamba2 layers of 128 heads); and eight B/C groups, whose
+    head blocks of 8 are smaller than the lane tile."""
+    B, P, N = 64, 64, 128
+    text = _compile(
+        ssd_step_pallas,
+        [((L, B, H, P, N), bf16), ((), jnp.int32), ((B, H, P), bf16), ((B, H), f32), ((H,), f32),
+         ((B, G, N), bf16), ((B, G, N), bf16), ((H,), f32)],
+        one_chip,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_mamba2_decode_step_updates_state_in_place_on_v5e(one_chip, monkeypatch):
+    """mamba2-1.3b's donated decode step at 64 slots on the chip's path: the
+    state kernel is in the program, and no copy or broadcast of a whole state
+    leaf is."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    cfg = get_config("mamba2-1.3b")
+    m = Model(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+        )
+
+    cache = on_chip(spec_shapes(m.cache_specs(ShapeSpec("d", "decode", 4096, 64)), cfg.dtype))
+    token = {"token": jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)}
+    step = jax.jit(m.decode_step, donate_argnums=(1,))
+    text = step.lower(on_chip(m.shapes()), cache, token).compile().as_text()
+    assert re.search(r"ssd_step[.\d]* = .* custom-call\(", text)
+    dims = ",".join(map(str, cache["state"].shape))
+    whole = re.compile(r"= \w+\[%s\]\S* (copy|copy-start|broadcast)\(" % dims)
+    offenders = [line.strip() for line in text.splitlines() if whole.search(line)]
+    assert not offenders, offenders

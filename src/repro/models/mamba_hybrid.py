@@ -6,11 +6,13 @@ shared SwiGLU expert.
     each layer ℓ:  x += r · mixer_ℓ(rms(x));  x += r · (Σ_top-k g_e SwiGLU_e + SwiGLU_shared)(rms(x))
     logits = rms(x) · Eᵀ / logits_scaling
 
-with r the residual multiplier.  The Mamba2 mixer is ``ssm``'s block (its
-``_mix``, the SSD kernel in prefill and the recurrent step in decode) with
-a conv bias; the attention mixer is GQA with no position embedding and
-scores scaled by ``attention_multiplier``, the Pallas flash kernel in
-prefill and the KV cache in decode.  The MoE computes the held experts'
+with r the residual multiplier.  The Mamba2 mixer is ``ssm``'s, with a
+conv bias: ``ssm.mixer`` over whole sequences (the SSD kernel) and
+``ssm.mixer_step`` in decode (the state updated in place); this module
+keeps only their residual, its loops and the conv tails' write order.  The
+attention mixer is GQA with no position embedding and scores scaled by
+``attention_multiplier``, the Pallas flash kernel in prefill and the KV
+cache in decode.  The MoE computes the held experts'
 part only (``moe.held_moe``): one device's share of an expert-parallel layer.
 
 Parameters: ``mamba`` holds the ssm block's leaves stacked over the Mamba2
@@ -133,12 +135,6 @@ def _residual(cfg: ModelConfig, x, y):
     return x + (y * cfg.residual_multiplier).astype(x.dtype)
 
 
-def _mamba_out(cfg: ModelConfig, p: dict, y, z):
-    """Gated RMSNorm and out projection of the Mamba2 mixer."""
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"], cfg.norm_eps)
-    return jnp.einsum("bse,ed->bsd", y, p["wo"])
-
-
 def _ffn(cfg: ModelConfig, p: dict, x, grouped: bool, route):
     """The MoE sub-layer with its residual; adds this layer's pairs and
     largest held-expert load to ``route``."""
@@ -170,8 +166,6 @@ def _forward(cfg: ModelConfig, params: dict, tokens, cache_len: int, mesh=None):
     """All layers over whole sequences; returns (hidden states, the cache
     a decode continues from, route counts)."""
     B, S = tokens.shape
-    di, nh, G, N, _ = ssm._dims(cfg)
-    P = cfg.ssm.head_dim
     x = _embed(cfg, params, tokens)
     route = jnp.zeros((2,), jnp.int32)
     parts: dict = {"conv": [], "state": [], "k": [], "v": []}
@@ -181,13 +175,7 @@ def _forward(cfg: ModelConfig, params: dict, tokens, cache_len: int, mesh=None):
             h, route = carry
             if kind == "mamba":
                 p = _at(params["mamba"], start + i)
-                hn = rmsnorm(h, p["ln"]["w"], cfg.norm_eps)
-                z, xs, Bm, Cm, dt, tail = ssm._mix(cfg, p, hn)
-                y, st = kops.ssd(
-                    xs.reshape(B, S, nh, P), dt, p["A_log"], Bm.reshape(B, S, G, N),
-                    Cm.reshape(B, S, G, N), p["D"], chunk=min(cfg.ssm.chunk, S), mesh=mesh,
-                )
-                y = _mamba_out(cfg, p, y.reshape(B, S, di), z)
+                y, tail, st = ssm.mixer(cfg, p, rmsnorm(h, p["ln"]["w"], cfg.norm_eps), mesh)
                 kept = {"conv": tail, "state": st.astype(h.dtype)}
             else:
                 p = _at(params["attn"], start + i)
@@ -231,20 +219,16 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=No
 
 
 def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
-    token = batch["token"]
-    B = token.shape[0]
-    di, nh, G, N, _ = ssm._dims(cfg)
-    P = cfg.ssm.head_dim
     lengths = cache["len"]
-    x = _embed(cfg, params, token[:, None])
+    x = _embed(cfg, params, batch["token"][:, None])
     route = jnp.zeros((2,), jnp.int32)
     cache = dict(cache)
     for kind, first, start, n in runs(cfg):
         if kind == "mamba":
-            # as ssm.decode_step: the state is updated in place by layer index
-            # (``kops.ssd_step_inplace``); each conv tail is written at the top
-            # of the next iteration (the last after the run), after every read
-            # of the old buffer
+            # as ssm.decode_step: ``ssm.mixer_step`` updates the state in place
+            # by layer index; each conv tail is written at the top of the next
+            # iteration (the last after the run), after every read of the old
+            # buffer
 
             def body(carry, i, first=first, start=start):
                 h, conv, state, tail, route = carry
@@ -253,12 +237,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
                 p = _at(params["mamba"], j)
                 hn = rmsnorm(h, p["ln"]["w"], cfg.norm_eps)
                 conv_st = lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
-                z, xs, Bm, Cm, dt, tail = ssm._mix(cfg, p, hn, conv_state=conv_st)
-                y, state = kops.ssd_step_inplace(
-                    state, j, xs[:, 0].reshape(B, nh, P), dt[:, 0], p["A_log"],
-                    Bm[:, 0].reshape(B, G, N), Cm[:, 0].reshape(B, G, N), p["D"],
-                )
-                h = _residual(cfg, h, _mamba_out(cfg, p, y.reshape(B, 1, di), z))
+                y, tail, state = ssm.mixer_step(cfg, p, hn, conv_st, state, j)
+                h = _residual(cfg, h, y)
                 h, route = _ffn(cfg, _at(params["moe"], first + i), h, False, route)
                 return (h, conv, state, tail.astype(conv.dtype), route), None
 

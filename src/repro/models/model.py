@@ -7,7 +7,7 @@ dry-run and benchmarks all share:
     m.param_specs()           spec tree (shapes/axes/init in one declaration)
     m.init(rng) / m.shapes()  arrays / ShapeDtypeStructs
     m.loss(params, batch)     → (loss, metrics)
-    m.prefill / m.decode_step serving steps
+    m.prefill / m.decode_step serving steps → (logits, cache, route counts)
     m.batch_specs(shape)      input Spec tree for an assigned ShapeSpec
     m.cache_specs(shape)      serving-state Spec tree for decode shapes
 """
@@ -76,28 +76,21 @@ class Model:
         return total, {"ce": ce, "aux": aux}
 
     # -- serving -----------------------------------------------------------------
-    @property
-    def routes(self) -> bool:
-        """Whether prefill and decode can also return the step's route counts
-        (``route=True``): token–expert pairs on the held experts, Σ over
-        layers of the largest held expert's pairs, experts held (int32 [3])."""
-        return self.cfg.family == "mamba_hybrid"
-
-    def prefill(self, params, batch, cache_len: int, route: bool = False):
-        if self.routes:
+    def prefill(self, params, batch, cache_len: int):
+        """(last-position logits, cache row, route counts) of a prompt."""
+        if self.cfg.family in ("moe", "ssm", "mamba_hybrid"):
             out = self.mod.prefill(self.cfg, params, batch, cache_len, mesh=self.mesh)
-            return out if route else out[:2]
-        if self.cfg.family in ("moe", "ssm"):
-            return self.mod.prefill(self.cfg, params, batch, cache_len, mesh=self.mesh)
-        return self.mod.prefill(self.cfg, params, batch, cache_len)
+        else:
+            out = self.mod.prefill(self.cfg, params, batch, cache_len)
+        return _with_route(out)
 
-    def decode_step(self, params, cache, batch, route: bool = False):
-        if self.routes:
-            out = self.mod.decode_step(self.cfg, params, cache, batch)
-            return out if route else out[:2]
+    def decode_step(self, params, cache, batch):
+        """(logits, cache, route counts) of one token for every slot."""
         if self.cfg.family == "moe":
-            return self.mod.decode_step(self.cfg, params, cache, batch, mesh=self.mesh)
-        return self.mod.decode_step(self.cfg, params, cache, batch)
+            out = self.mod.decode_step(self.cfg, params, cache, batch, mesh=self.mesh)
+        else:
+            out = self.mod.decode_step(self.cfg, params, cache, batch)
+        return _with_route(out)
 
     # -- input/cache declarations (drive smoke tests AND the dry-run) -------------
     def batch_specs(self, shape: ShapeSpec) -> dict:
@@ -137,3 +130,11 @@ class Model:
     def model_flops_per_token(self) -> int:
         """6·N_active — the §Roofline MODEL_FLOPS convention."""
         return 6 * self.cfg.active_params()
+
+
+def _with_route(out):
+    """A family's serving output as (logits, cache, route counts).  The
+    counts are a family's with held experts (int32 [3]: token–expert pairs
+    on the held experts, Σ over layers of the largest held expert's pairs,
+    experts held), and an int32 [0] for every other family."""
+    return out if len(out) == 3 else (*out, jnp.zeros((0,), jnp.int32))

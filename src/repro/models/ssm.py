@@ -3,7 +3,9 @@
 Block: RMSNorm → {z, x, B, C, dt} projections → causal depthwise conv on
 (x|B|C) → SSD chunked scan (kernels.ops.ssd) → gated RMSNorm → out proj.
 Decode carries (conv tail, SSM state) — O(1) in sequence length, which is
-why this arch runs the long_500k shape.
+why this arch runs the long_500k shape.  The mixer (all of the block but
+its norm and residual) is ``mixer`` over whole sequences and
+``mixer_step`` for one token; ``mamba_hybrid`` runs the same two.
 
 Sharding: SSD heads over "model" (64 heads / 16 = 4), projections
 column/row-parallel, conv channels over "model".
@@ -77,12 +79,19 @@ def _mix(cfg: ModelConfig, p: dict, h, conv_state=None):
     return z, xs, Bm, Cm, dt, conv_tail
 
 
-def block(cfg: ModelConfig, p: dict, x, mesh=None):
+def _out(cfg: ModelConfig, p: dict, y, z):
+    """Gated RMSNorm and out projection."""
+    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"], cfg.norm_eps)
+    return jnp.einsum("bse,ed->bsd", y, p["wo"])
+
+
+def mixer(cfg: ModelConfig, p: dict, hn, mesh=None):
+    """The mixer over whole sequences (SSD chunked scan): normed input →
+    (out, conv tail, SSM state)."""
     di, nh, G, N, _ = _dims(cfg)
-    B, S, _ = x.shape
-    h = rmsnorm(x, p["ln"]["w"], cfg.norm_eps)
-    z, xs, Bm, Cm, dt, _ = _mix(cfg, p, h)
-    y, _ = kops.ssd(
+    B, S, _ = hn.shape
+    z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, p, hn)
+    y, st = kops.ssd(
         xs.reshape(B, S, nh, cfg.ssm.head_dim),
         dt,
         p["A_log"],
@@ -92,9 +101,32 @@ def block(cfg: ModelConfig, p: dict, x, mesh=None):
         chunk=min(cfg.ssm.chunk, S),
         mesh=mesh,
     )
-    y = y.reshape(B, S, di)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm_g"], cfg.norm_eps)
-    return x + jnp.einsum("bse,ed->bsd", y, p["wo"])
+    return _out(cfg, p, y.reshape(B, S, di), z), conv_tail, st
+
+
+def mixer_step(cfg: ModelConfig, p: dict, hn, conv_state, state, l):
+    """The mixer for one token: normed input [B, 1, d], the layer's conv
+    state, the stacked SSM state and the layer's index in it → (out, conv
+    tail, state), layer ``l`` of the state updated in place."""
+    di, nh, G, N, _ = _dims(cfg)
+    B = hn.shape[0]
+    z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, p, hn, conv_state=conv_state)
+    y, state = kops.ssd_step_inplace(
+        state,
+        l,
+        xs[:, 0].reshape(B, nh, cfg.ssm.head_dim),
+        dt[:, 0],
+        p["A_log"],
+        Bm[:, 0].reshape(B, G, N),
+        Cm[:, 0].reshape(B, G, N),
+        p["D"],
+    )
+    return _out(cfg, p, y.reshape(B, 1, di), z), conv_tail, state
+
+
+def block(cfg: ModelConfig, p: dict, x, mesh=None):
+    out, _, _ = mixer(cfg, p, rmsnorm(x, p["ln"]["w"], cfg.norm_eps), mesh)
+    return x + out
 
 
 def forward_train(cfg: ModelConfig, params: dict, batch: dict, mesh=None):
@@ -128,27 +160,12 @@ def cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
 
 def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=None):
     tokens = batch["tokens"]
-    di, nh, G, N, _ = _dims(cfg)
     B, S = tokens.shape
     x = embed(params["embed"], tokens)
 
     def body(h, pl):
-        hn = rmsnorm(h, pl["ln"]["w"], cfg.norm_eps)
-        z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn)
-        y, st = kops.ssd(
-            xs.reshape(B, S, nh, cfg.ssm.head_dim),
-            dt,
-            pl["A_log"],
-            Bm.reshape(B, S, G, N),
-            Cm.reshape(B, S, G, N),
-            pl["D"],
-            chunk=min(cfg.ssm.chunk, S),
-            mesh=mesh,
-        )
-        y = y.reshape(B, S, di)
-        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"], cfg.norm_eps)
-        h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
-        return h, (conv_tail, st.astype(x.dtype))
+        out, conv_tail, st = mixer(cfg, pl, rmsnorm(h, pl["ln"]["w"], cfg.norm_eps), mesh)
+        return h + out, (conv_tail, st.astype(x.dtype))
 
     x, (convs, states) = model_scan(cfg, _remat(cfg, body), x, params["blocks"])
     x = rmsnorm(x, params["ln_f"]["w"], cfg.norm_eps)
@@ -158,10 +175,7 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict, cache_len: int, mesh=No
 
 
 def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
-    token = batch["token"]
-    di, nh, G, N, _ = _dims(cfg)
-    B = token.shape[0]
-    x = embed(params["embed"], token[:, None])
+    x = embed(params["embed"], batch["token"][:, None])
 
     # The cache leaves are loop-carried and updated one layer slice at a time,
     # in place in the donated cache (stacked scan outputs would be a fresh
@@ -174,21 +188,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         conv = lax.dynamic_update_index_in_dim(conv, tail, jnp.maximum(l - 1, 0), 0)
         conv_st = lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
         hn = rmsnorm(h, pl["ln"]["w"], cfg.norm_eps)
-        z, xs, Bm, Cm, dt, conv_tail = _mix(cfg, pl, hn, conv_state=conv_st)
-        y, state = kops.ssd_step_inplace(
-            state,
-            l,
-            xs[:, 0].reshape(B, nh, cfg.ssm.head_dim),
-            dt[:, 0],
-            pl["A_log"],
-            Bm[:, 0].reshape(B, G, N),
-            Cm[:, 0].reshape(B, G, N),
-            pl["D"],
-        )
-        y = y.reshape(B, 1, di)
-        y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), pl["norm_g"], cfg.norm_eps)
-        h = h + jnp.einsum("bse,ed->bsd", y, pl["wo"])
-        return (h, conv, state, conv_tail.astype(conv.dtype), l + 1), None
+        out, conv_tail, state = mixer_step(cfg, pl, hn, conv_st, state, l)
+        return (h + out, conv, state, conv_tail.astype(conv.dtype), l + 1), None
 
     conv = cache["conv"]
     init = (x, conv, cache["state"], conv[0], jnp.int32(0))

@@ -28,7 +28,8 @@ def param_artifacts(model: Model, partitioner: Partitioner):
 def prefill_artifacts(model: Model, partitioner: Partitioner, shape: ShapeSpec):
     """jit + (param, batch) shapes/shardings for the prefill_* shapes.
 
-    Output cache is sharded like cache_specs; logits unconstrained.
+    Output cache is sharded like cache_specs; logits and route counts
+    unconstrained.
     """
     p_shapes, p_shardings = param_artifacts(model, partitioner)
     b_specs = model.batch_specs(shape)
@@ -40,7 +41,7 @@ def prefill_artifacts(model: Model, partitioner: Partitioner, shape: ShapeSpec):
     fn = jax.jit(
         lambda p, b: model.prefill(p, b, shape.seq_len),
         in_shardings=(p_shardings, b_shardings),
-        out_shardings=(None, c_shardings),
+        out_shardings=(None, c_shardings, None),
     )
     return fn, (p_shapes, b_shapes), (p_shardings, b_shardings)
 
@@ -59,9 +60,9 @@ def decode_artifacts(model: Model, partitioner: Partitioner, shape: ShapeSpec):
     b_shapes = spec_shapes(b_specs, model.cfg.dtype)
     b_shardings = _shardings(partitioner, b_shapes, spec_axes(b_specs))
     fn = jax.jit(
-        lambda p, c, b: model.decode_step(p, c, b),
+        model.decode_step,
         in_shardings=(p_shardings, c_shardings, b_shardings),
-        out_shardings=(None, c_shardings),
+        out_shardings=(None, c_shardings, None),
         donate_argnums=(1,),
     )
     return fn, (p_shapes, c_shapes, b_shapes), (p_shardings, c_shardings, b_shardings)

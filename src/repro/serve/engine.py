@@ -7,9 +7,11 @@ incoming prompt and splicing its cache row into the live batch cache; every
 THAPI ``prefill``/``decode_step`` spans — the serving tally of §4.3 — inside
 one ``engine_step`` span around the whole step; each admitted request's time
 in the queue is a ``queue_wait`` span, and the token readback a D2H
-``memcpy``.  A model with held experts (``Model.routes``) reads its route
-counts back with the tokens, one array per step, and records them as one
-``moe_route`` pair per prefill and per decode step.
+``memcpy``.  The prefill and decode programs pick the tokens themselves
+(argmax) and pack them with the model's route counts into one array, read
+back once per admission and once per step; a model with held experts has
+counts, recorded as one ``moe_route`` pair per prefill and per decode step,
+and any other model none.
 
 The decode step is a TracedJit with explicit cache shardings (batch over the
 data axes, heads over model), donated cache — the same artifact the dry-run
@@ -115,25 +117,20 @@ class ServeEngine:
         self.cache = jax.tree_util.tree_map(jnp.zeros_like, self.cache)
         if cache_shardings is not None:
             self.cache = jax.device_put(self.cache, cache_shardings)
-        # a model with held experts returns its route counts: the program
-        # then picks the tokens too and packs [tokens, counts] into one
-        # array, read back as the step's tokens are
-        self._routes = model.routes
+        # the program picks the tokens and packs [tokens, route counts] into
+        # one array, the step's only readback
         V = model.cfg.vocab_size
-        if self._routes:
 
-            def decode(p, c, b):
-                logits, c, route = model.decode_step(p, c, b, route=True)
-                nxt = jnp.argmax(logits[:, 0, :V], axis=-1).astype(jnp.int32)
-                return nxt, c, jnp.concatenate([nxt, route])
+        def decode(p, c, b):
+            logits, c, route = model.decode_step(p, c, b)
+            nxt = jnp.argmax(logits[:, 0, :V], axis=-1).astype(jnp.int32)
+            return nxt, c, jnp.concatenate([nxt, route])
 
-        else:
-            decode = lambda p, c, b: model.decode_step(p, c, b)  # noqa: E731
         self._decode = TracedJit(
             decode,
             name=f"decode_step[{model.cfg.name}]",
             donate_argnums=(1,),
-            out_shardings=(None, cache_shardings) + ((None,) if self._routes else ()),
+            out_shardings=(None, cache_shardings, None),
             flops=2 * model.cfg.active_params() * B,
         )
         self.slots: List[Optional[Request]] = [None] * B
@@ -165,25 +162,26 @@ class ServeEngine:
         """Prefill a single prompt, splice its cache row into the live batch."""
         record_queue_wait(r.rid, r.t_submit)
         toks = r.prompt[None, :]
-        with prefill_span(r.rid, 1, int(toks.shape[1])):
-            batch = {"tokens": jnp.asarray(toks)}
-            if self.model.cfg.family == "audio":
-                batch["frames"] = jnp.zeros(
-                    (1, self.model.cfg.encdec.enc_positions, self.model.cfg.d_model),
-                    self.cache_dtype(),
-                )
-            S = int(toks.shape[1])
+        S = int(toks.shape[1])
+        with prefill_span(r.rid, 1, S):
+            # the model's other inputs (an audio model's frames, a VLM's
+            # patch embeddings) are zeros
+            specs = self.model.batch_specs(ShapeSpec("serve", "prefill", S, 1))
+            batch = {
+                k: jnp.zeros(s.shape, s.dtype)
+                for k, s in spec_shapes(specs, self.model.cfg.dtype).items()
+                if k != "tokens"
+            }
+            batch["tokens"] = jnp.asarray(toks)
             if S not in self._prefill_jits:  # one compile per prompt length
                 self._prefill_jits[S] = TracedJit(
                     self._prefill_program(), name=f"prefill[{self.model.cfg.name}/S{S}]"
                 )
             out, row = self._prefill_jits[S](self.params, batch)
-        if self._routes:
-            vals = np.asarray(out)  # the first token and the route counts
-            first = int(vals[0])
+        vals = np.asarray(out)  # the first token, then any route counts
+        first = int(vals[0])
+        if vals.size > 1:
             record_moe_route(False, *(int(v) for v in vals[1:]))
-        else:
-            first = int(jnp.argmax(out[0, 0, : self.model.cfg.vocab_size]))
         r.out_tokens.append(first)
         self._tok = self._tok.at[slot].set(first)
         self.cache = jax.tree_util.tree_map(
@@ -191,20 +189,15 @@ class ServeEngine:
         )
 
     def _prefill_program(self):
-        """(logits, cache row), or with route counts ([first token, counts], row)."""
+        """([first token, route counts], cache row) of a prompt."""
         cache_len, V = self.cfg.cache_len, self.model.cfg.vocab_size
-        if not self._routes:
-            return lambda p, b: self.model.prefill(p, b, cache_len)
 
         def prefill(p, b):
-            logits, row, route = self.model.prefill(p, b, cache_len, route=True)
+            logits, row, route = self.model.prefill(p, b, cache_len)
             first = jnp.argmax(logits[0, 0, :V]).astype(jnp.int32)
             return jnp.concatenate([first[None], route]), row
 
         return prefill
-
-    def cache_dtype(self):
-        return jnp.bfloat16 if self.model.cfg.dtype == "bfloat16" else jnp.float32
 
     @staticmethod
     def _splice(cache_leaf, row_leaf, slot: int):
@@ -237,26 +230,17 @@ class ServeEngine:
             return 0
         rid = self.slots[active[0]].rid
         with decode_step_span(rid, len(active), self.cfg.cache_len) as sp:
-            if self._routes:
-                nxt, self.cache, packed = self._decode(self.params, self.cache, {"token": self._tok})
-            else:
-                logits, self.cache = self._decode(self.params, self.cache, {"token": self._tok})
-                nxt = jnp.argmax(
-                    logits[:, 0, : self.model.cfg.vocab_size], axis=-1
-                ).astype(jnp.int32)
+            nxt, self.cache, packed = self._decode(self.params, self.cache, {"token": self._tok})
             sp.outs["tokens_out"] = len(active)
         self._tok = nxt
         if self.adaptive is not None:
             self.adaptive.tick(engine=self)
         if self.cluster_adaptive is not None:
             self.cluster_adaptive.tick()
-        if self._routes:
-            host = traced_device_get(packed)
-            B = len(self.slots)
+        host = traced_device_get(packed)  # the tokens, then any route counts
+        B = len(self.slots)
+        if host.size > B:
             record_moe_route(True, *(int(v) for v in host[B:]))
-            host = host[:B]
-        else:
-            host = traced_device_get(nxt)
         for i in active:
             r = self.slots[i]
             r.out_tokens.append(int(host[i]))

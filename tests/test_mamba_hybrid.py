@@ -69,11 +69,11 @@ def test_prefill_then_decode_match_the_reference(f32):
     z, model, w = f32
     rng = np.random.default_rng(5)
     seq = rng.integers(0, z["V"], size=(2, 72)).astype(np.int32)
-    logits, cache = model.prefill(w, {"tokens": jnp.asarray(seq[:, :64])}, 96)
+    logits, cache, _ = model.prefill(w, {"tokens": jnp.asarray(seq[:, :64])}, 96)
     got = [np.asarray(logits[:, 0, : z["V"]])]
     step = jax.jit(model.decode_step, donate_argnums=(1,))
     for t in range(64, 71):
-        logits, cache = step(w, cache, {"token": jnp.asarray(seq[:, t])})
+        logits, cache, _ = step(w, cache, {"token": jnp.asarray(seq[:, t])})
         got.append(np.asarray(logits[:, 0, : z["V"]]))
     got = np.stack(got, axis=1)
     for b in range(2):
@@ -91,7 +91,7 @@ def test_bf16_program_stays_close_to_the_reference():
     model = Model(program_config(BF16, z))
     w = make_weights(BF16, SEED, "bfloat16")
     seq = np.random.default_rng(6).integers(0, z["V"], size=(1, 128)).astype(np.int32)
-    logits, _ = model.prefill(w, {"tokens": jnp.asarray(seq)}, 160)
+    logits, _, _ = model.prefill(w, {"tokens": jnp.asarray(seq)}, 160)
     want = _ref_logits(z, w, seq[0], np.asarray([127]))[0]
     served = int(jnp.argmax(logits[0, 0, : z["V"]]))
     assert want.max() - want[served] < 0.004
@@ -164,15 +164,15 @@ def test_continuous_batching_gives_each_slot_its_own_logits(f32):
 
     def batch_step(cache, tok):
         """One decode step of the batch; every admitted slot against its own."""
-        logits, cache = step(w, cache, {"token": jnp.asarray(tok)})
+        logits, cache, _ = step(w, cache, {"token": jnp.asarray(tok)})
         for slot, (row, t) in alone.items():
-            one, row = step(w, row, {"token": jnp.asarray([t])})
+            one, row, _ = step(w, row, {"token": jnp.asarray([t])})
             np.testing.assert_allclose(np.asarray(logits[slot]), np.asarray(one[0]), **TOL)
             alone[slot] = (row, int(jnp.argmax(one[0, 0, : z["V"]])))
         return cache, np.asarray(jnp.argmax(logits[:, 0, : z["V"]], axis=-1)).astype(np.int32)
 
     for slot, p in enumerate(prompts):  # an admission, then a step, for each
-        logits, row = model.prefill(w, {"tokens": jnp.asarray(p[None])}, 96)
+        logits, row, _ = model.prefill(w, {"tokens": jnp.asarray(p[None])}, 96)
         cache = jax.tree_util.tree_map(lambda c, r: ServeEngine._splice(c, r, slot), cache, row)
         tok[slot] = int(jnp.argmax(logits[0, 0, : z["V"]]))
         alone[slot] = (row, tok[slot])
@@ -190,10 +190,10 @@ def test_donated_decode_steps_match_prefill(f32, impl, monkeypatch):
     z, model, w = f32
     seq = jnp.asarray(np.random.default_rng(10).integers(0, z["V"], size=(2, 8)), jnp.int32)
     step = jax.jit(model.decode_step, donate_argnums=(1,))
-    _, cache = model.prefill(w, {"tokens": seq[:, :5]}, 16)
+    _, cache, _ = model.prefill(w, {"tokens": seq[:, :5]}, 16)
     for t in range(5, 8):
-        logits, cache = step(w, cache, {"token": seq[:, t]})
-        want, want_cache = model.prefill(w, {"tokens": seq[:, : t + 1]}, 16)
+        logits, cache, _ = step(w, cache, {"token": seq[:, t]})
+        want, want_cache, _ = model.prefill(w, {"tokens": seq[:, : t + 1]}, 16)
         np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(want[:, 0]), **TOL)
     for k in want_cache:  # the chunked prefill scan against the steps: as test_models_smoke's
         np.testing.assert_allclose(
@@ -256,7 +256,7 @@ def test_engine_records_route_counts_with_the_tokens(tmp_path):
         assert 0 < iv.exit["pairs"] <= 2 * 2 * 5
         assert iv.exit["pairs"] / 4 <= iv.exit["max_load"] <= iv.exit["pairs"]
     # the prefill's counts are those the program returns for the prompt
-    _, _, want = model.prefill(w, {"tokens": jnp.asarray(prompts[0][None].astype(np.int32))}, 80, route=True)
+    _, _, want = model.prefill(w, {"tokens": jnp.asarray(prompts[0][None].astype(np.int32))}, 80)
     first = min((iv for iv in route if not iv.entry["decode"]), key=lambda iv: iv.ts)
     assert [first.exit["pairs"], first.exit["max_load"], first.entry["held"]] == np.asarray(want).tolist()
     # the readback is the only D2H copy of a decode step, as without experts

@@ -83,7 +83,7 @@ def test_smoke_prefill_decode(arch, rng):
     params = m.init(jax.random.PRNGKey(1))
     batch = make_batch(m, SMOKE_PREFILL, rng)
     cache_len = SMOKE_DECODE.seq_len
-    logits, cache = jax.jit(lambda p, b: m.prefill(p, b, cache_len))(params, batch)
+    logits, cache, _ = jax.jit(lambda p, b: m.prefill(p, b, cache_len))(params, batch)
     B = SMOKE_PREFILL.global_batch
     assert logits.shape[0] == B and logits.shape[1] == 1
     assert bool(jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
@@ -91,7 +91,7 @@ def test_smoke_prefill_decode(arch, rng):
     step = jax.jit(m.decode_step)
     tok = jnp.argmax(logits[:, 0, : cfg.vocab_size], axis=-1).astype(jnp.int32)
     for _ in range(3):
-        logits, cache = step(params, cache, {"token": tok})
+        logits, cache, _ = step(params, cache, {"token": tok})
         assert logits.shape[0] == B
         assert bool(jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
         tok = jnp.argmax(logits[:, 0, : cfg.vocab_size], axis=-1).astype(jnp.int32)
@@ -123,10 +123,10 @@ def test_decode_matches_prefill_continuation(rng):
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 12)), jnp.int32)
     cache_len = 32
     # full prefill over 12 tokens
-    full_logits, _ = m.prefill(params, {"tokens": toks}, cache_len)
+    full_logits, _, _ = m.prefill(params, {"tokens": toks}, cache_len)
     # prefill over 11 then decode the 12th
-    _, cache = m.prefill(params, {"tokens": toks[:, :-1]}, cache_len)
-    step_logits, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
+    _, cache, _ = m.prefill(params, {"tokens": toks[:, :-1]}, cache_len)
+    step_logits, _, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
     np.testing.assert_allclose(
         np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
     )
@@ -137,9 +137,9 @@ def test_ssm_decode_matches_prefill_continuation(rng):
     m = Model(cfg)
     params = m.init(jax.random.PRNGKey(3))
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 8)), jnp.int32)
-    full_logits, _ = m.prefill(params, {"tokens": toks}, 16)
-    _, cache = m.prefill(params, {"tokens": toks[:, :-1]}, 16)
-    step_logits, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
+    full_logits, _, _ = m.prefill(params, {"tokens": toks}, 16)
+    _, cache, _ = m.prefill(params, {"tokens": toks[:, :-1]}, 16)
+    step_logits, _, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
     np.testing.assert_allclose(
         np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
     )
@@ -164,10 +164,10 @@ def _donated_decode_steps_match_prefill(rng):
     params = m.init(jax.random.PRNGKey(3))
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 8)), jnp.int32)
     step = jax.jit(m.decode_step, donate_argnums=(1,))
-    _, cache = m.prefill(params, {"tokens": toks[:, :5]}, 16)
+    _, cache, _ = m.prefill(params, {"tokens": toks[:, :5]}, 16)
     for t in range(5, 8):
-        step_logits, cache = step(params, cache, {"token": toks[:, t]})
-        full_logits, full_cache = m.prefill(params, {"tokens": toks[:, : t + 1]}, 16)
+        step_logits, cache, _ = step(params, cache, {"token": toks[:, t]})
+        full_logits, full_cache, _ = m.prefill(params, {"tokens": toks[:, : t + 1]}, 16)
         np.testing.assert_allclose(
             np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
         )
@@ -203,9 +203,9 @@ def test_hybrid_decode_matches_prefill_continuation(rng):
     m = Model(cfg)
     params = m.init(jax.random.PRNGKey(4))
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(2, 8)), jnp.int32)
-    full_logits, _ = m.prefill(params, {"tokens": toks}, 16)
-    _, cache = m.prefill(params, {"tokens": toks[:, :-1]}, 16)
-    step_logits, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
+    full_logits, _, _ = m.prefill(params, {"tokens": toks}, 16)
+    _, cache, _ = m.prefill(params, {"tokens": toks[:, :-1]}, 16)
+    step_logits, _, _ = m.decode_step(params, cache, {"token": toks[:, -1]})
     np.testing.assert_allclose(
         np.asarray(full_logits[:, 0]), np.asarray(step_logits[:, 0]), rtol=2e-4, atol=2e-4
     )

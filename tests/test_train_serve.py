@@ -12,6 +12,7 @@ from repro.checkpoint import Checkpointer, latest_checkpoint
 from repro.configs import get_config
 from repro.jaxcompat import make_mesh
 from repro.models import Model, ShapeSpec
+from repro.models.param import shapes as spec_shapes
 from repro.sharding import Partitioner
 from repro.serve import Request, ServeConfig, ServeEngine
 from repro.train import TrainConfig, Trainer, TrainerConfig
@@ -191,19 +192,28 @@ def test_engine_batched_decode(smoke_model):
     assert all(0 <= t < smoke_model.cfg.vocab_size for r in done for t in r.out_tokens)
 
 
-def test_engine_matches_sequential_decode(smoke_model):
-    """Batched engine output for one request == naive prefill+decode loop."""
-    params = smoke_model.init(jax.random.PRNGKey(0))
-    prompt = np.arange(10) % smoke_model.cfg.vocab_size
-    eng = ServeEngine(smoke_model, params, ServeConfig(batch_slots=2, cache_len=40, max_new_tokens=4))
+@pytest.mark.parametrize(
+    "arch", ["stablelm-3b", "mamba2-1.3b", "whisper-medium", "granite-4.0-h-small"]
+)
+def test_engine_matches_sequential_decode(arch, mesh):
+    """Batched engine output for one request == naive prefill+decode loop:
+    a model with route counts, models without, and an audio model whose
+    frames the engine makes (zeros)."""
+    model = Model(get_config(arch).smoke(), mesh)
+    params = model.init(jax.random.PRNGKey(0))
+    V = model.cfg.vocab_size
+    prompt = np.arange(16) % V  # a whole number of SSD chunks
+    eng = ServeEngine(model, params, ServeConfig(batch_slots=2, cache_len=40, max_new_tokens=4))
     r = eng.submit(prompt)
     eng.run_until_drained()
     # naive reference
-    logits, cache = smoke_model.prefill(params, {"tokens": jnp.asarray(prompt[None])}, 40)
-    toks = [int(jnp.argmax(logits[0, 0, : smoke_model.cfg.vocab_size]))]
+    specs = spec_shapes(model.batch_specs(ShapeSpec("t", "prefill", 16, 1)), model.cfg.dtype)
+    batch = {k: jnp.zeros(s.shape, s.dtype) for k, s in specs.items()}
+    batch["tokens"] = jnp.asarray(prompt[None], jnp.int32)
+    logits, cache, _ = jax.jit(lambda p, b: model.prefill(p, b, 40))(params, batch)
+    toks = [int(jnp.argmax(logits[0, 0, :V]))]
+    step = jax.jit(model.decode_step)
     for _ in range(3):
-        logits, cache = smoke_model.decode_step(
-            params, cache, {"token": jnp.asarray([toks[-1]], jnp.int32)}
-        )
-        toks.append(int(jnp.argmax(logits[0, 0, : smoke_model.cfg.vocab_size])))
+        logits, cache, _ = step(params, cache, {"token": jnp.asarray([toks[-1]], jnp.int32)})
+        toks.append(int(jnp.argmax(logits[0, 0, :V])))
     assert r.out_tokens == toks

@@ -1,9 +1,10 @@
 """Batched serving engine: prefill + continuous decode over slot batches.
 
 The engine owns a fixed slot batch (decode efficiency demands static shapes
-on TPU).  Requests queue; a slot is (re)filled by running prefill for the
-incoming prompt and splicing its cache row into the live batch cache; every
-``step()`` decodes one token for all active slots.  Both phases run through
+on TPU).  Requests queue; a slot is (re)filled by one prefill program, which
+takes the live batch cache donated, computes the incoming prompt's cache row
+and first token, and writes both in place at the slot; every ``step()``
+decodes one token for all active slots.  Both phases run through
 THAPI ``prefill``/``decode_step`` spans — the serving tally of §4.3 — inside
 one ``engine_step`` span around the whole step; each admitted request's time
 in the queue is a ``queue_wait`` span, and the token readback a D2H
@@ -13,9 +14,10 @@ back once per admission and once per step; a model with held experts has
 counts, recorded as one ``moe_route`` pair per prefill and per decode step,
 and any other model none.
 
-The decode step is a TracedJit with explicit cache shardings (batch over the
-data axes, heads over model), donated cache — the same artifact the dry-run
-lowers for the decode_32k / long_500k shapes.
+The prefill and decode programs are TracedJits with explicit cache shardings
+(batch over the data axes, heads over model) and the cache donated; the
+decode step is the same artifact the dry-run lowers for the decode_32k /
+long_500k shapes.
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.clock import now
@@ -113,6 +116,7 @@ class ServeEngine:
                 pspecs,
                 is_leaf=lambda x: isinstance(x, P),
             )
+        self._cache_shardings = cache_shardings
         self.cache = spec_init(cache_specs, jax.random.PRNGKey(0), model.cfg.dtype)
         self.cache = jax.tree_util.tree_map(jnp.zeros_like, self.cache)
         if cache_shardings is not None:
@@ -159,7 +163,7 @@ class ServeEngine:
         return admitted
 
     def _prefill_into(self, slot: int, r: Request) -> None:
-        """Prefill a single prompt, splice its cache row into the live batch."""
+        """Prefill a single prompt into the live batch at ``slot``."""
         record_queue_wait(r.rid, r.t_submit)
         toks = r.prompt[None, :]
         S = int(toks.shape[1])
@@ -175,41 +179,33 @@ class ServeEngine:
             batch["tokens"] = jnp.asarray(toks)
             if S not in self._prefill_jits:  # one compile per prompt length
                 self._prefill_jits[S] = TracedJit(
-                    self._prefill_program(), name=f"prefill[{self.model.cfg.name}/S{S}]"
+                    self._prefill_program(),
+                    name=f"prefill[{self.model.cfg.name}/S{S}]",
+                    donate_argnums=(2,),
+                    out_shardings=(None, self._cache_shardings, None),
                 )
-            out, row = self._prefill_jits[S](self.params, batch)
+            out, self.cache, self._tok = self._prefill_jits[S](
+                self.params, batch, self.cache, self._tok, np.int32(slot)
+            )
         vals = np.asarray(out)  # the first token, then any route counts
         first = int(vals[0])
         if vals.size > 1:
             record_moe_route(False, *(int(v) for v in vals[1:]))
         r.out_tokens.append(first)
-        self._tok = self._tok.at[slot].set(first)
-        self.cache = jax.tree_util.tree_map(
-            lambda c, v: self._splice(c, v, slot), self.cache, row
-        )
 
     def _prefill_program(self):
-        """([first token, route counts], cache row) of a prompt."""
+        """(params, batch, cache, tok, slot) -> ([first token, route counts],
+        cache, tok): the prompt's cache row and first token written at slot."""
         cache_len, V = self.cfg.cache_len, self.model.cfg.vocab_size
 
-        def prefill(p, b):
+        def prefill(p, b, cache, tok, slot):
             logits, row, route = self.model.prefill(p, b, cache_len)
             first = jnp.argmax(logits[0, 0, :V]).astype(jnp.int32)
-            return jnp.concatenate([first[None], route]), row
+            cache = jax.tree_util.tree_map(lambda c, v: splice(c, v, slot), cache, row)
+            tok = lax.dynamic_update_slice(tok, first[None], (slot,))
+            return jnp.concatenate([first[None], route]), cache, tok
 
         return prefill
-
-    @staticmethod
-    def _splice(cache_leaf, row_leaf, slot: int):
-        """Insert the size-1-batch prefill row at slot. Batch axis is the one
-        where the shapes differ (layers lead; batch follows)."""
-        for ax in range(cache_leaf.ndim):
-            if row_leaf.shape[ax] == 1 and cache_leaf.shape[ax] != 1:
-                idx = [slice(None)] * cache_leaf.ndim
-                idx[ax] = slice(slot, slot + 1)
-                return cache_leaf.at[tuple(idx)].set(row_leaf.astype(cache_leaf.dtype))
-        # scalar-per-batch leaves (e.g. len)
-        return cache_leaf.at[slot].set(row_leaf.reshape(-1)[0].astype(cache_leaf.dtype))
 
     # -- decode loop -----------------------------------------------------------------
     def step(self) -> int:
@@ -276,3 +272,17 @@ class ServeEngine:
 
         t = self.live_tally()
         return None if t is None else render(t, top=top)
+
+
+def splice(cache_leaf, row_leaf, slot):
+    """``cache_leaf`` with the size-1-batch prefill row written at ``slot``.
+    The batch axis is the first where the shapes differ (layers lead; batch
+    follows; a per-slot leaf such as ``len`` has it first); where none
+    differs, the batch is one slot and the row is all of it."""
+    row = row_leaf.astype(cache_leaf.dtype)
+    for ax in range(cache_leaf.ndim):
+        if row.shape[ax] == 1 and cache_leaf.shape[ax] != 1:
+            start = [0] * cache_leaf.ndim
+            start[ax] = slot
+            return lax.dynamic_update_slice(cache_leaf, row, start)
+    return row

@@ -151,7 +151,7 @@ def test_continuous_batching_gives_each_slot_its_own_logits(f32):
     """Prompts of different lengths admitted into one slot batch at
     different steps (the engine's splice, both kinds of state): each slot's
     logits are those of its request decoded alone."""
-    from repro.serve import ServeEngine
+    from repro.serve.engine import splice
 
     z, model, w = f32
     rng = np.random.default_rng(9)
@@ -173,7 +173,7 @@ def test_continuous_batching_gives_each_slot_its_own_logits(f32):
 
     for slot, p in enumerate(prompts):  # an admission, then a step, for each
         logits, row, _ = model.prefill(w, {"tokens": jnp.asarray(p[None])}, 96)
-        cache = jax.tree_util.tree_map(lambda c, r: ServeEngine._splice(c, r, slot), cache, row)
+        cache = jax.tree_util.tree_map(lambda c, r: splice(c, r, slot), cache, row)
         tok[slot] = int(jnp.argmax(logits[0, 0, : z["V"]]))
         alone[slot] = (row, tok[slot])
         cache, tok = batch_step(cache, tok)

@@ -14,6 +14,7 @@ from repro.configs import get_config
 from repro.core import TraceConfig, Tracer
 from repro.core.babeltrace import intervals_of
 from repro.core.clock import ProfileClock, profile_clock
+from repro.core.interception import nbytes_of
 from repro.core.plugins.tally import tally_trace
 from repro.jaxcompat import make_mesh
 from repro.models import Model
@@ -89,6 +90,20 @@ def test_serving_calls_are_counted_as_before(served):
     assert calls["memcpy"] == steps  # the token readback of each decode step
 
 
+def test_each_admission_is_one_dispatch_donating_the_cache(served, engine):
+    """N admissions and M decode steps are N prefill and N + M dispatch
+    calls, the count the benchmark checks; each prefill program's dispatch
+    donates the whole batch cache, into which it writes the prompt's row."""
+    _, reqs, steps, ivs = served
+    dispatches = _named(ivs, "dispatch")
+    prefills = [iv for iv in dispatches if iv.entry["fn"].startswith("prefill[")]
+    assert len(_named(ivs, "prefill")) == len(prefills) == len(reqs)
+    assert len(dispatches) == len(reqs) + steps
+    cache_bytes = nbytes_of(engine.cache)
+    assert cache_bytes > 0
+    assert [iv.entry["donated_bytes"] for iv in prefills] == [cache_bytes] * len(reqs)
+
+
 def test_idle_session_records_nothing(tmp_path):
     with Tracer(TraceConfig(out_dir=str(tmp_path / "t"), mode="default")) as tr:
         time.sleep(0.12)  # two consumer ticks with nothing to drain
@@ -115,7 +130,11 @@ def test_programs_take_the_traced_names(engine):
     assert "module @jit_decode_step_mamba2_1_3b_smoke " in text
     engine.submit(np.arange(16) % 100)
     engine.run_until_drained()
-    text = engine._prefill_jits[16].lower(engine.params, {"tokens": np.zeros((1, 16), np.int32)}).as_text()
+    text = (
+        engine._prefill_jits[16]
+        .lower(engine.params, {"tokens": np.zeros((1, 16), np.int32)}, engine.cache, engine._tok, np.int32(0))
+        .as_text()
+    )
     assert "module @jit_prefill_mamba2_1_3b_smoke_S16 " in text
 
 
